@@ -23,6 +23,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
                     help="comma-separated substrings of bench names")

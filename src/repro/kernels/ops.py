@@ -1,9 +1,10 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
-On TPU the compiled kernels run natively; elsewhere (this CPU container)
-``interpret=True`` executes the kernel bodies in Python for correctness
-validation, and the model code itself uses the XLA twins in
-repro/models/attention.py (the dry-run lowers those).
+On TPU the compiled kernels run natively.  On CPU ``interpret=True``
+executes the kernel bodies in Python for correctness validation (the
+model code itself uses the XLA twins in repro/models/attention.py).  Any
+other backend is an error: a kernel never silently runs interpreted on
+an accelerator.
 """
 from __future__ import annotations
 
@@ -17,7 +18,11 @@ from repro.kernels import ref
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas kernels run natively on TPU or "
+                           f"interpreted on CPU, not on {backend!r}")
+    return backend == "cpu"
 
 
 def gram(x: jnp.ndarray, use_pallas: bool = True) -> jnp.ndarray:

@@ -8,9 +8,10 @@ quantize    — blockwise symmetric int8/int4 quantization with stochastic
               rather than drawn with ``pltpu.prng_random_bits`` so the
               identical kernel body validates under ``interpret=True`` on
               CPU (the in-kernel PRNG has no CPU lowering); on TPU the
-              bits land in VMEM alongside the block.  Deterministic
-              round-to-nearest is the special case bits == 2**31
-              (offset exactly 0.5).
+              bits land in VMEM alongside the block.  The offset is the
+              top 24 bits scaled by 2**-24 (shift, int32, f32: the TPU
+              has no uint32 -> f32 cast).  Deterministic round-to-nearest
+              is the special case bits == 2**31 (offset exactly 0.5).
 dequantize  — codes * scale back to f32.
 abs_threshold_count / abs_threshold_mask
             — the two reductions behind threshold-refinement top-k
@@ -32,7 +33,7 @@ BLOCK = 1024          # elements per quantization group (one (8,128) tile)
 ROWS_PER_STEP = 256   # grid tile: (256, 1024) f32 = 1 MB working set
 
 _DET_BITS = jnp.uint32(2 ** 31)    # uint32 whose [0,1) image is exactly 0.5
-_INV_2_32 = float(2.0 ** -32)
+_INV_2_24 = float(2.0 ** -24)
 
 
 def _pad_rows(x2: jnp.ndarray, block_rows: int):
@@ -47,8 +48,11 @@ def _pad_rows(x2: jnp.ndarray, block_rows: int):
 def _quantize_kernel(x_ref, bits_ref, codes_ref, scale_ref, *, qmax):
     x = x_ref[...].astype(jnp.float32)                      # (R, B)
     absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
-    scale = jnp.where(absmax > 0, absmax / qmax, 1.0)
-    r = bits_ref[...].astype(jnp.float32) * _INV_2_32       # [0, 1)
+    # jit rewrites `/ qmax` as `* (1/qmax)` and eager code does not; the
+    # explicit multiply keeps kernel and oracle bit-identical everywhere
+    scale = jnp.where(absmax > 0, absmax * (1.0 / qmax), 1.0)
+    # top 24 bits -> [0, 1) on a 2**-24 grid; 2**31 -> exactly 0.5
+    r = (bits_ref[...] >> 8).astype(jnp.int32).astype(jnp.float32) * _INV_2_24
     q = jnp.clip(jnp.floor(x / scale + r), -qmax, qmax)
     codes_ref[...] = q.astype(jnp.int8)
     scale_ref[...] = scale

@@ -52,8 +52,10 @@ def quantize(x2: jnp.ndarray, bits: jnp.ndarray, qmax: int = 127):
     """
     x = x2.astype(jnp.float32)
     absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
-    scale = jnp.where(absmax > 0, absmax / qmax, 1.0)
-    r = bits.astype(jnp.float32) * (2.0 ** -32)
+    # jit rewrites `/ qmax` as `* (1/qmax)` and eager code does not; the
+    # explicit multiply keeps kernel and oracle bit-identical everywhere
+    scale = jnp.where(absmax > 0, absmax * (1.0 / qmax), 1.0)
+    r = (bits >> 8).astype(jnp.int32).astype(jnp.float32) * (2.0 ** -24)
     q = jnp.clip(jnp.floor(x / scale + r), -qmax, qmax)
     return q.astype(jnp.int8), scale
 

@@ -21,10 +21,12 @@ import numpy as np
 from repro.configs import get_config
 from repro.configs.base import FIRMConfig
 from repro.fed.engine import EngineConfig, FederatedTrainer
+from repro.launch import compile_cache
 from repro.train import checkpoint
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama-3.2-1b")
     ap.add_argument("--preset", default="smoke", choices=["smoke", "full"])

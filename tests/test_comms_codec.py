@@ -35,23 +35,28 @@ def test_quantize_pallas_matches_ref(qmax, stochastic, rows):
         bits = jnp.full(x.shape, _DET_BITS, jnp.uint32)
     cp, sp = ops.quantize(x, bits, qmax)                 # Pallas interpret
     cr, sr = ref.quantize(x, bits, qmax)                 # jnp oracle
-    assert (np.asarray(cp) == np.asarray(cr)).all()
-    np.testing.assert_allclose(np.asarray(sp), np.asarray(sr), rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(cp), np.asarray(cr))
+    np.testing.assert_array_equal(np.asarray(sp), np.asarray(sr))
     dq_p = ops.dequantize(cp, sp)
     dq_r = ref.dequantize(cr, sr)
-    np.testing.assert_allclose(np.asarray(dq_p), np.asarray(dq_r),
-                               rtol=1e-6, atol=1e-7)
+    np.testing.assert_array_equal(np.asarray(dq_p), np.asarray(dq_r))
     # per-element error bound: one quantization step
     err = np.abs(np.asarray(dq_p) - np.asarray(x))
     assert (err <= np.asarray(sp) + 1e-6).all()
 
 
 def test_quantize_deterministic_rounds_to_nearest():
-    x = jnp.asarray([[0.0, 0.24, 0.26, -0.26, 1.0] + [0.0] * 1019])
+    # 0.25 and -0.25 sit exactly on a half step: the offset from
+    # _DET_BITS is exactly 0.5, so they round half up
+    x = jnp.asarray([[0.0, 0.24, 0.26, -0.26, 1.0, 0.25, -0.25]
+                     + [0.0] * 1017])
     bits = jnp.full(x.shape, _DET_BITS, jnp.uint32)
     codes, scales = ref.quantize(x, bits, qmax=2)        # scale = 0.5
-    got = np.asarray(codes[0, :5])
-    np.testing.assert_array_equal(got, [0, 0, 1, -1, 2])
+    got = np.asarray(codes[0, :7])
+    np.testing.assert_array_equal(got, [0, 0, 1, -1, 2, 1, 0])
+    kcodes, kscales = ops.quantize(x, bits, qmax=2)
+    np.testing.assert_array_equal(np.asarray(kcodes), np.asarray(codes))
+    np.testing.assert_array_equal(np.asarray(kscales), np.asarray(scales))
 
 
 def test_stochastic_rounding_unbiased():

@@ -11,12 +11,6 @@ def _compile(f, *args):
     return jax.jit(f).lower(*args).compile()
 
 
-def _xla_cost(compiled) -> dict:
-    """cost_analysis() returns a dict in older jaxlib, [dict] in newer."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
-
-
 def test_scan_matches_unrolled():
     w = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     x = jax.ShapeDtypeStruct((32, 128), jnp.float32)
@@ -36,7 +30,7 @@ def test_scan_matches_unrolled():
     t2 = hlo_cost.analyze(_compile(unrolled, w, x).as_text())
     assert t1["flops"] == pytest.approx(t2["flops"], rel=0.1)
     # XLA's own counter misses the 10x
-    xla = _xla_cost(_compile(scanned, w, x))["flops"]
+    xla = _compile(scanned, w, x).cost_analysis()["flops"]
     assert t1["flops"] > 5 * xla
 
 
@@ -56,7 +50,7 @@ def test_unrolled_bytes_match_xla():
 
     c = _compile(f, a)
     t = hlo_cost.analyze(c.as_text())
-    xla = _xla_cost(c)["bytes accessed"]
+    xla = c.cost_analysis()["bytes accessed"]
     assert t["bytes"] == pytest.approx(xla, rel=0.5)
 
 

@@ -165,3 +165,12 @@ def test_ssd_kernel_matches_model_ssm_block():
     np.testing.assert_allclose(np.asarray(y_seq),
                                np.asarray(jnp.concatenate(ys, 1)),
                                rtol=3e-3, atol=3e-3)
+
+
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    assert ops._interpret() is True                # tests run on CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._interpret()

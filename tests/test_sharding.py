@@ -11,7 +11,7 @@ from repro.configs.base import FIRMConfig
 from repro.launch import sharding as sh
 from repro.launch import specs as specs_lib
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 
 
 class _FakePath:
@@ -70,7 +70,7 @@ def test_divisibility_guard_replicates():
 def test_batch_spec_data_axes():
     assert sh.batch_spec((256, 4096), MESH) == P("data", None)
     assert sh.batch_spec((1, 4096), MESH) == P(None, None)
-    multi = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+    multi = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     assert sh.batch_spec((64, 128), multi,
                          data_axes=("pod", "data")) == \
         P(("pod", "data"), None)
