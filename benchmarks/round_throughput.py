@@ -21,8 +21,7 @@ from __future__ import annotations
 import json
 import time
 
-from benchmarks.common import cell_sink_spec, make_trainer, row, trace_path
-from repro.obs import TraceBuilder, jitwatch
+from benchmarks.common import cell_sink_spec, make_trainer, row
 
 CLIENT_COUNTS = (4, 8, 16)
 # K=1, B=1: the communication-bound regime FIRM targets (a round IS
@@ -67,16 +66,8 @@ def _measure_fused(n_clients: int, r: int = FUSED_R) -> dict:
     tr.run(r)                                   # compile/warmup chunk
     d0 = tr.jit_dispatches
     t0 = time.perf_counter()
-    # record jit entries during the timed chunks so --trace-out can
-    # render compile-vs-execute host wall-clock spans per program
-    with jitwatch.record() as jlog:
-        tr.run(r * FUSED_CHUNKS)
+    tr.run(r * FUSED_CHUNKS)
     dt = time.perf_counter() - t0
-    tp = trace_path(name)
-    if tp:
-        tb = TraceBuilder()
-        tb.add_host_spans(jlog.spans)
-        tb.write(tp)
     tr.obs.close()
     rounds = r * FUSED_CHUNKS
     return {

@@ -48,6 +48,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import jitwatch
+
 
 @dataclasses.dataclass
 class Payload:
@@ -413,24 +415,30 @@ class ErrorFeedback(Codec):
         self._rt_stacked_jit = None
 
     # jitted handles are cached per codec instance (one instance serves
-    # every client of a trainer, so each trainer compiles these once)
+    # every client of a trainer, so each trainer compiles these once);
+    # they are named programs of the device trace (repro.obs.jitwatch),
+    # kept out of the engine's dispatch counts
     def _jit_rt_flat(self):
         if self._rt_flat_jit is None:
             def fn(f, s, k):
                 adj = f + s
                 arrays, dec = self.inner.encode_decode_traced(adj, key=k)
                 return arrays, dec, adj - dec
-            self._rt_flat_jit = jax.jit(fn)
+            self._rt_flat_jit = jitwatch.wrap("ef_roundtrip_flat", fn,
+                                              counted=False)
         return self._rt_flat_jit
 
     def _jit_rt_stacked(self):
+        # the stacked round trip is the engine's uplink
         if self._rt_stacked_jit is None:
             def fn(f, s, k):
-                adj = f + s
-                arrays, dec = self.inner.encode_decode_traced_stacked(
-                    adj, keys=k)
-                return arrays, dec, adj - dec
-            self._rt_stacked_jit = jax.jit(fn)
+                with jax.named_scope("uplink_codec"):
+                    adj = f + s
+                    arrays, dec = self.inner.encode_decode_traced_stacked(
+                        adj, keys=k)
+                    return arrays, dec, adj - dec
+            self._rt_stacked_jit = jitwatch.wrap(
+                "ef_roundtrip_stacked", fn, counted=False)
         return self._rt_stacked_jit
 
     def encode(self, tree, state=None, *, key=None):
@@ -506,9 +514,10 @@ class ErrorFeedback(Codec):
         return dec, adj - dec
 
     def roundtrip_traced_stacked(self, flats, states, *, keys=None):
-        adj = flats + states
-        dec, _ = self.inner.roundtrip_traced_stacked(adj, (), keys=keys)
-        return dec, adj - dec
+        with jax.named_scope("uplink_codec"):
+            adj = flats + states
+            dec, _ = self.inner.roundtrip_traced_stacked(adj, (), keys=keys)
+            return dec, adj - dec
 
     def decode(self, payload: Payload):
         return self.inner.decode(payload)

@@ -114,9 +114,10 @@ from repro.rlhf.sampling import generate
 @functools.lru_cache(maxsize=None)
 def _jit_ref_logprobs(cfg: ModelConfig):
     def ref_lp(ref_params, tokens):
-        out = transformer.forward_seq(cfg, ref_params, tokens)
-        return ppo.token_logprobs(out["logits"], tokens)
-    return jitwatch.wrap("ref_logprobs", jax.jit(ref_lp))
+        with jax.named_scope("ref_forward"):
+            out = transformer.forward_seq(cfg, ref_params, tokens)
+            return ppo.token_logprobs(out["logits"], tokens)
+    return jitwatch.wrap("ref_logprobs", ref_lp)
 
 
 def _make_round_fn(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
@@ -147,8 +148,9 @@ def _make_round_fn(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
                                             max_new=max_new)
             r = rewards_lib.score_batch_banded(bh, bx, tokens, mask, m,
                                                length_tol)
-            ref_out = transformer.forward_seq(cfg, ref_params, tokens)
-            ref_lp = ppo.token_logprobs(ref_out["logits"], tokens)
+            with jax.named_scope("ref_forward"):
+                ref_out = transformer.forward_seq(cfg, ref_params, tokens)
+                ref_lp = ppo.token_logprobs(ref_out["logits"], tokens)
             batch = ppo.PPOBatch(tokens, mask, old_lp, ref_lp, r)
             return alg.traced_step(cfg, cfc, st, frozen, batch, p, extra)
 
@@ -157,8 +159,9 @@ def _make_round_fn(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
 
         def body(carry, xs):
             step_idx, keys_c = xs
-            prompts = sample_prompt_block(seeds, counts0 + step_idx, probs,
-                                          b, prompt_len, cfg.vocab)
+            with jax.named_scope("sample_prompts"):
+                prompts = sample_prompt_block(seeds, counts0 + step_idx,
+                                              probs, b, prompt_len, cfg.vocab)
             new_state, metrics = vstep(carry, prompts, keys_c, band_h,
                                        band_x, pref)
             keep = {k: metrics[k] for k in ("lam", "rewards", "kl")}
@@ -179,28 +182,33 @@ def _jit_vec_round(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
     donated)."""
     return jitwatch.wrap(
         f"vec_round[{kernel}]",
-        jax.jit(_make_round_fn(cfg, cfc, kernel, prompt_len,
-                               max_new, length_tol, has_pref),
-                donate_argnums=(0,)))
+        _make_round_fn(cfg, cfc, kernel, prompt_len, max_new, length_tol,
+                       has_pref),
+        donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_unstack(n: int):
     return jitwatch.wrap(
-        "unstack",
-        jax.jit(lambda tree: tuple(fedavg.unstack_tree(tree, n))))
+        "unstack", lambda tree: tuple(fedavg.unstack_tree(tree, n)))
 
 
 _stack_trees_jit = jitwatch.wrap(
-    "stack_trees", jax.jit(lambda *trees: fedavg.stack_trees(trees)))
+    "stack_trees", lambda *trees: fedavg.stack_trees(trees))
 
-# all C client deltas vs the broadcast anchor flattened in ONE batched
-# tree op -> (C, d) f32; row c is bit-identical to tree_to_flat(delta_c)
-_delta_flat_jit = jitwatch.wrap("delta_flat", jax.jit(
-    lambda stacked, anchor: jnp.concatenate(
-        [(a - b).astype(jnp.float32).reshape(a.shape[0], -1)
-         for a, b in zip(jax.tree_util.tree_leaves(stacked),
-                         jax.tree_util.tree_leaves(anchor))], axis=1)))
+
+def _delta_flat(stacked, anchor):
+    """All C client deltas vs the broadcast anchor flattened in ONE
+    batched tree op -> (C, d) f32; row c is bit-identical to
+    tree_to_flat(delta_c)."""
+    with jax.named_scope("delta"):
+        return jnp.concatenate(
+            [(a - b).astype(jnp.float32).reshape(a.shape[0], -1)
+             for a, b in zip(jax.tree_util.tree_leaves(stacked),
+                             jax.tree_util.tree_leaves(anchor))], axis=1)
+
+
+_delta_flat_jit = jitwatch.wrap("delta_flat", _delta_flat)
 
 
 @functools.lru_cache(maxsize=None)
@@ -213,30 +221,33 @@ def _jit_flat_aggregate(spec):
     """
 
     def fn(anchor, flats, staleness, pow):
-        w = fedavg.staleness_weights(staleness, pow)
-        agg = fedavg.fedavg_flat_weighted(flats, w)
-        return jax.tree_util.tree_map(lambda b, d: b + d, anchor,
-                                      codec_lib.flat_to_tree(agg, spec))
+        with jax.named_scope("aggregate"):
+            w = fedavg.staleness_weights(staleness, pow)
+            agg = fedavg.fedavg_flat_weighted(flats, w)
+            return jax.tree_util.tree_map(lambda b, d: b + d, anchor,
+                                          codec_lib.flat_to_tree(agg, spec))
 
-    return jitwatch.wrap("flat_aggregate", jax.jit(fn))
+    return jitwatch.wrap("flat_aggregate", fn)
 
 
 def _summary_device_fn(lams, rewards_mean, kl_mean, stacked_trainable,
                        rewards_pc):
     """All round-summary statistics computed device-side; the engine does
     ONE host transfer per round (jax.device_get of this dict)."""
-    return {
-        "rewards": rewards_mean,
-        "lam_mean": lams.mean(0),
-        "lam_disagreement": drift.lambda_disagreement(lams)["pairwise_mean"],
-        "param_drift": drift.param_drift_stacked(stacked_trainable),
-        "kl": kl_mean,
-        "per_client_lam": lams,
-        "rewards_per_client": rewards_pc,
-    }
+    with jax.named_scope("summary"):
+        return {
+            "rewards": rewards_mean,
+            "lam_mean": lams.mean(0),
+            "lam_disagreement":
+                drift.lambda_disagreement(lams)["pairwise_mean"],
+            "param_drift": drift.param_drift_stacked(stacked_trainable),
+            "kl": kl_mean,
+            "per_client_lam": lams,
+            "rewards_per_client": rewards_pc,
+        }
 
 
-_summary_device = jitwatch.wrap("summary_device", jax.jit(_summary_device_fn))
+_summary_device = jitwatch.wrap("summary_device", _summary_device_fn)
 
 
 class LocalPhaseResult(NamedTuple):
@@ -364,11 +375,7 @@ def _jit_fused_rounds(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
                                     seeds, counts0, probs, band_h,
                                     band_x, gen_keys, pref, extra)
 
-            flat_deltas = jnp.concatenate(
-                [(a - b).astype(jnp.float32).reshape(a.shape[0], -1)
-                 for a, b in zip(
-                     jax.tree_util.tree_leaves(new_part.trainable),
-                     jax.tree_util.tree_leaves(broadcast))], axis=1)
+            flat_deltas = _delta_flat(new_part.trainable, broadcast)
             up_keys = []
             for _p in range(n_part):
                 rng, kk = _split_next(rng)
@@ -376,12 +383,13 @@ def _jit_fused_rounds(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
             decoded, ul_part = ul.roundtrip_traced_stacked(
                 flat_deltas, ul_part, keys=jnp.stack(up_keys))
 
-            w = fedavg.staleness_weights(jnp.zeros(n_part, jnp.float32),
-                                         jnp.float32(0.5))
-            agg = fedavg.fedavg_flat_weighted(decoded, w)
-            g_tree = jax.tree_util.tree_map(
-                lambda b, d: b + d, broadcast,
-                codec_lib.flat_to_tree(agg, spec))
+            with jax.named_scope("aggregate"):
+                w = fedavg.staleness_weights(
+                    jnp.zeros(n_part, jnp.float32), jnp.float32(0.5))
+                agg = fedavg.fedavg_flat_weighted(decoded, w)
+                g_tree = jax.tree_util.tree_map(
+                    lambda b, d: b + d, broadcast,
+                    codec_lib.flat_to_tree(agg, spec))
 
             if full:
                 states = new_part
@@ -395,20 +403,21 @@ def _jit_fused_rounds(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
                 counts = counts.at[idx].add(k_steps)
 
             lams = ms["lam"][-1]                              # (P, M)
-            ys = {
-                # staged means match _local_phase_vectorized bit-for-bit
-                # (see the comment there)
-                "rewards": ms["rewards"].mean(0).mean(0),
-                "lam_mean": lams.mean(0),
-                "lam_disagreement":
-                    drift.lambda_disagreement(lams)["pairwise_mean"],
-                "param_drift":
-                    drift.param_drift_stacked(new_part.trainable),
-                "kl": ms["kl"].mean(0).mean(0),
-                "per_client_lam": lams,
-                "rewards_per_client": ms["rewards"].mean(0),
-                "participants": idx,
-            }
+            with jax.named_scope("summary"):
+                ys = {
+                    # staged means match _local_phase_vectorized
+                    # bit-for-bit (see the comment there)
+                    "rewards": ms["rewards"].mean(0).mean(0),
+                    "lam_mean": lams.mean(0),
+                    "lam_disagreement":
+                        drift.lambda_disagreement(lams)["pairwise_mean"],
+                    "param_drift":
+                        drift.param_drift_stacked(new_part.trainable),
+                    "kl": ms["kl"].mean(0).mean(0),
+                    "per_client_lam": lams,
+                    "rewards_per_client": ms["rewards"].mean(0),
+                    "participants": idx,
+                }
             return (states, g_tree, ul_state, dl_state, counts, rng), ys
 
         init = (carry.states, global_tr, carry.ul_state, carry.dl_state,
@@ -418,8 +427,8 @@ def _jit_fused_rounds(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
         return (FusedCarry(states, ul_state, dl_state, counts, rng),
                 g_tree, ys)
 
-    return jitwatch.wrap(f"fused_rounds[{kernel}]",
-                         jax.jit(fused, donate_argnums=(0,)))
+    return jitwatch.wrap(f"fused_rounds[{kernel}]", fused,
+                         donate_argnums=(0,))
 
 
 class FederatedTrainer:
@@ -602,6 +611,13 @@ class FederatedTrainer:
         return out
 
     def run_round(self, participants: Optional[List[int]] = None) -> dict:
+        # the host phases are spans on the profiler's clock while a JAX
+        # profiler trace runs (``jitwatch.span``), so an idle gap on the
+        # device reads as the phase the host was in
+        with jitwatch.span("round", round=self._round_idx):
+            return self._run_round(participants)
+
+    def _run_round(self, participants: Optional[List[int]]) -> dict:
         fc = self._fc_for_algorithm()
         if participants is None:
             participants = self._sample_participants()
@@ -609,24 +625,28 @@ class FederatedTrainer:
         dispatch0 = self.jit_dispatches
         # broadcast θ_t through the downlink codec; every client receives
         # (and trains from) the same decoded broadcast
-        dl_payload, self._downlink_state, broadcast = \
-            self.downlink_codec.roundtrip(
-                self.global_trainable, self._downlink_state,
-                key=self._next_key())
-        for c in participants:
-            self.ledger.send_down(dl_payload)
+        with jitwatch.span("round/keys"):
+            dl_key = self._next_key()
+        with jitwatch.span("round/downlink"):
+            dl_payload, self._downlink_state, broadcast = \
+                self.downlink_codec.roundtrip(
+                    self.global_trainable, self._downlink_state, key=dl_key)
+            for c in participants:
+                self.ledger.send_down(dl_payload)
 
-        mode, plan = self._local_phase_mode(participants)
-        if mode == "vec":
-            # the cohort's shared config, not the base fc: a UNIFORM
-            # client_local_steps override still forms one cohort but its
-            # K differs from fc.local_steps
-            res = self._local_phase_vectorized(plan[0].cfc, participants,
-                                               broadcast)
-        elif mode == "cohort":
-            res = self._local_phase_cohorts(plan, participants, broadcast)
-        else:
-            res = self._local_phase_loop(fc, participants, broadcast)
+        with jitwatch.span("round/local_phase"):
+            mode, plan = self._local_phase_mode(participants)
+            if mode == "vec":
+                # the cohort's shared config, not the base fc: a UNIFORM
+                # client_local_steps override still forms one cohort but
+                # its K differs from fc.local_steps
+                res = self._local_phase_vectorized(plan[0].cfc,
+                                                   participants, broadcast)
+            elif mode == "cohort":
+                res = self._local_phase_cohorts(plan, participants,
+                                                broadcast)
+            else:
+                res = self._local_phase_loop(fc, participants, broadcast)
 
         # participating clients transmit adapted-param deltas through the
         # uplink codec (residuals stay client-local); the delta against
@@ -635,28 +655,36 @@ class FederatedTrainer:
         # (flat) Payload boundary — one batched kernel dispatch for
         # quantize codecs — and the server aggregates the decoded (C, d)
         # matrix in one matvec + single unflatten
-        flat_deltas = _delta_flat_jit(res.stacked_trainable, broadcast)
-        self.jit_dispatches += 1
-        up_keys = [self._next_key() for _ in participants]
-        payloads, new_states, decoded = self.uplink_codec.roundtrip_stacked(
-            flat_deltas, self._delta_spec,
-            [self._uplink_state[c] for c in participants], keys=up_keys)
-        for ci, c in enumerate(participants):
-            self._uplink_state[c] = new_states[ci]
-            self.ledger.send_up(payloads[ci])
+        with jitwatch.span("round/uplink"):
+            flat_deltas = _delta_flat_jit(res.stacked_trainable, broadcast)
+            self.jit_dispatches += 1
+            with jitwatch.span("round/keys"):
+                up_keys = [self._next_key() for _ in participants]
+            payloads, new_states, decoded = \
+                self.uplink_codec.roundtrip_stacked(
+                    flat_deltas, self._delta_spec,
+                    [self._uplink_state[c] for c in participants],
+                    keys=up_keys)
+            for ci, c in enumerate(participants):
+                self._uplink_state[c] = new_states[ci]
+                self.ledger.send_up(payloads[ci])
         # kept for offline payload analysis (entropy-coded size estimates
         # in benchmarks/codec_tradeoff.py) — references only, no copies
         self._last_up_payloads = payloads
-        self.global_trainable = self._aggregate_flat(
-            broadcast, decoded, jnp.zeros(len(participants), jnp.float32))
+        with jitwatch.span("round/aggregate"):
+            self.global_trainable = self._aggregate_flat(
+                broadcast, decoded,
+                jnp.zeros(len(participants), jnp.float32))
         self.ledger.next_round()
         self._round_idx += 1
 
         # metrics were accumulated on device; ONE host transfer per round
-        stats = _summary_device(res.lams, res.rewards_mean, res.kl_mean,
-                                res.stacked_trainable, res.rewards_pc)
-        self.jit_dispatches += 1
-        host = jax.device_get(stats)
+        with jitwatch.span("round/summary"):
+            stats = _summary_device(res.lams, res.rewards_mean,
+                                    res.kl_mean, res.stacked_trainable,
+                                    res.rewards_pc)
+            self.jit_dispatches += 1
+            host = jax.device_get(stats)
         self.host_transfers += 1
         summary = obs_records.round_summary(
             stats=host,
@@ -863,9 +891,10 @@ class FederatedTrainer:
                 # per-client generation keys, drawn in the loop path's
                 # order (step-major, then participant order) for exact
                 # key parity
-                gen_keys = jnp.stack(
-                    [jnp.stack([self._next_key() for _ in participants])
-                     for _ in range(k_steps)])
+                with jitwatch.span("round/keys"):
+                    gen_keys = jnp.stack(
+                        [jnp.stack([self._next_key() for _ in participants])
+                         for _ in range(k_steps)])
             extra = self.algorithm.traced_extra(cfc, self.ec)
             fn = _jit_vec_round(self.cfg, cfc, self.algorithm.kernel,
                                 self.ec.prompt_len, self.ec.max_new,

@@ -433,11 +433,8 @@ class ScheduledTrainer:
         self.history.extend(out)
         return self.history
 
-    def export_trace(self, path: str, host_spans=None) -> dict:
+    def export_trace(self, path: str) -> dict:
         """Write the accumulated schedule as Chrome/Perfetto trace-event
-        JSON (open at https://ui.perfetto.dev).  ``host_spans`` optionally
-        adds ``repro.obs.jitwatch`` spans as a host wall-clock process.
-        Validates before writing; returns the trace dict."""
-        if host_spans:
-            self.trace.add_host_spans(host_spans)
+        JSON (open at https://ui.perfetto.dev).  Validates before writing;
+        returns the trace dict."""
         return self.trace.write(path)

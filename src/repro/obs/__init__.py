@@ -7,8 +7,9 @@ Layered over the federated engine without touching its hot path:
   metrics   MetricsPipeline fanning records into pluggable sinks
             (memory / jsonl / csv)
   trace     Chrome/Perfetto trace-event rendering of the simulated
-            schedule and host jit wall-clock
-  jitwatch  jit-entry spans: dispatches, compiles, wall time
+            schedule
+  jitwatch  named engine programs: dispatches, compiles, host spans on
+            the profiler's clock, the op-to-layer map of a device trace
   audit     reconcile ExecutionPlan predictions against observed runs
   debug     env/flag-wired jax_debug_nans / x64 toggles
 

@@ -2,7 +2,7 @@
 
 ``TraceBuilder`` accumulates trace events in the Chrome trace-event JSON
 format (the ``{"traceEvents": [...]}`` object form) that
-https://ui.perfetto.dev opens directly.  Two processes:
+https://ui.perfetto.dev opens directly, as one process:
 
   pid 1 "simulated schedule"  the scheduler's simulated clock.  Thread 0
         is the server (round/barrier spans, aggregation instants); thread
@@ -14,14 +14,11 @@ https://ui.perfetto.dev opens directly.  Two processes:
         fedbuff uploads connect to the aggregation that consumed them via
         flow arrows, and the event-queue depth renders as a counter
         track.
-  pid 2 "host wall-clock"     real time: one span per jitted-program
-        entry recorded by ``repro.obs.jitwatch``, with compile-triggering
-        calls flagged (``args.compiled``) — compile vs execute cost is
-        visible per program.
 
 All simulated timestamps are seconds and render as microseconds (the
-trace-event unit); host spans are offset to start at t=0 of their own
-process so the two timelines don't visually interleave.
+trace-event unit).  Real time lives in the JAX profiler's trace, where
+``repro.obs.jitwatch`` names each engine program and host phase on the
+device's clock.
 """
 from __future__ import annotations
 
@@ -29,11 +26,9 @@ import json
 from typing import Dict, List, Optional, Sequence, Tuple
 
 SIM_PID = 1
-HOST_PID = 2
 SERVER_TID = 0
 
 SIM_PROCESS_NAME = "simulated schedule"
-HOST_PROCESS_NAME = "host wall-clock"
 
 
 def _us(seconds: float) -> float:
@@ -120,31 +115,13 @@ class TraceBuilder:
                             "cat": "counter", "name": name, "ts": _us(t),
                             "args": {k: float(v) for k, v in values.items()}})
 
-    # ------------------------------------------------------- host time
-    def add_host_spans(self, spans, t_base: Optional[float] = None) -> None:
-        """Render ``jitwatch`` spans (perf_counter seconds) on the host
-        process, offset so the first span starts at 0."""
-        if not spans:
-            return
-        if t_base is None:
-            t_base = min(s.t0 for s in spans)
-        self._thread(HOST_PID, 0, "jit entry")
-        for s in spans:
-            self.events.append({
-                "ph": "X", "pid": HOST_PID, "tid": 0, "cat": "host",
-                "name": s.name, "ts": _us(s.t0 - t_base),
-                "dur": _us(s.dur),
-                "args": {"compiled": bool(s.compiled)}})
-
     # ------------------------------------------------------- export
     def to_dict(self) -> dict:
         meta = []
-        for pid, pname in ((SIM_PID, SIM_PROCESS_NAME),
-                           (HOST_PID, HOST_PROCESS_NAME)):
-            if any(e["pid"] == pid for e in self.events):
-                meta.append({"ph": "M", "pid": pid, "tid": 0, "ts": 0,
-                             "name": "process_name",
-                             "args": {"name": pname}})
+        if self.events:
+            meta.append({"ph": "M", "pid": SIM_PID, "tid": 0, "ts": 0,
+                         "name": "process_name",
+                         "args": {"name": SIM_PROCESS_NAME}})
         return {"traceEvents": meta + list(self.events),
                 "displayTimeUnit": "ms"}
 

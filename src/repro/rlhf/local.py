@@ -45,6 +45,25 @@ def init_client_state(trainable, m: int, d_model: int,
     )
 
 
+def _adam(fc: FIRMConfig, direction, state: ClientState):
+    with jax.named_scope("local_step/adam"):
+        return optim.adam_update(direction, state.opt, state.trainable,
+                                 lr=fc.actor_lr, max_grad_norm=1.0)
+
+
+def _critic_kl(fc: FIRMConfig, state: ClientState, feats, r_tok, mask,
+               metrics):
+    """TD update of the M critics and the adaptive-KL coefficient:
+    (new critic, TD error, new KL coefficient)."""
+    with jax.named_scope("local_step/critic_kl"):
+        r_w = critic_lib.r_w_bound(r_max=1.0)
+        new_critic, td_err = critic_lib.td_update(
+            state.critic, feats, r_tok, mask, fc.gamma, fc.critic_lr, r_w)
+        new_kl = kl_lib.adaptive_kl_update(state.kl_coef, metrics["kl"],
+                                           fc.kl_target)
+    return new_critic, td_err, new_kl
+
+
 def firm_local_step(cfg: ModelConfig, fc: FIRMConfig, state: ClientState,
                     frozen, batch: ppo.PPOBatch,
                     aux: Optional[dict] = None, gram_fn=None,
@@ -55,20 +74,18 @@ def firm_local_step(cfg: ModelConfig, fc: FIRMConfig, state: ClientState,
     (M,) array — the vmap-safe signature the vectorized engine uses to run
     heterogeneous per-client preferences through a single trace.
     """
-    grads, losses, (metrics, feats, r_tok, rets, mask) = \
-        ppo.per_objective_grads(cfg, fc, state.trainable, frozen,
-                                state.critic, batch, state.kl_coef, aux)
-    eta = firm.eta_schedule(state.step + 1) if fc.lambda_smoothing else None
-    res = firm.resolve(grads, fc, prev_lam=state.lam, eta=eta,
-                       gram_fn=gram_fn, preference=preference)
-    new_trainable, new_opt, gnorm = optim.adam_update(
-        res.direction, state.opt, state.trainable, lr=fc.actor_lr,
-        max_grad_norm=1.0)
-    r_w = critic_lib.r_w_bound(r_max=1.0)
-    new_critic, td_err = critic_lib.td_update(
-        state.critic, feats, r_tok, mask, fc.gamma, fc.critic_lr, r_w)
-    new_kl = kl_lib.adaptive_kl_update(state.kl_coef, metrics["kl"],
-                                       fc.kl_target)
+    with jax.named_scope("local_step/grads"):
+        grads, losses, (metrics, feats, r_tok, rets, mask) = \
+            ppo.per_objective_grads(cfg, fc, state.trainable, frozen,
+                                    state.critic, batch, state.kl_coef, aux)
+    with jax.named_scope("local_step/mgda"):
+        eta = (firm.eta_schedule(state.step + 1) if fc.lambda_smoothing
+               else None)
+        res = firm.resolve(grads, fc, prev_lam=state.lam, eta=eta,
+                           gram_fn=gram_fn, preference=preference)
+    new_trainable, new_opt, gnorm = _adam(fc, res.direction, state)
+    new_critic, td_err, new_kl = _critic_kl(fc, state, feats, r_tok, mask,
+                                            metrics)
     new_state = ClientState(new_trainable, new_critic, new_opt, res.lam,
                             new_kl, state.step + 1)
     metrics = dict(metrics, losses=losses, lam=res.lam,
@@ -81,9 +98,10 @@ def fedcmoo_local_grads(cfg: ModelConfig, fc: FIRMConfig,
                         state: ClientState, frozen, batch: ppo.PPOBatch,
                         aux: Optional[dict] = None):
     """FedCMOO client phase 1: compute and 'transmit' the M gradients."""
-    grads, losses, (metrics, feats, r_tok, rets, mask) = \
-        ppo.per_objective_grads(cfg, fc, state.trainable, frozen,
-                                state.critic, batch, state.kl_coef, aux)
+    with jax.named_scope("local_step/grads"):
+        grads, losses, (metrics, feats, r_tok, rets, mask) = \
+            ppo.per_objective_grads(cfg, fc, state.trainable, frozen,
+                                    state.critic, batch, state.kl_coef, aux)
     return grads, losses, (metrics, feats, r_tok, mask)
 
 
@@ -91,15 +109,11 @@ def fedcmoo_local_apply(fc: FIRMConfig, state: ClientState, grads,
                         lam: jnp.ndarray, extras):
     """FedCMOO client phase 2: apply the server-broadcast λ."""
     metrics, feats, r_tok, mask = extras
-    direction = firm.mgda.combine(grads, lam)
-    new_trainable, new_opt, gnorm = optim.adam_update(
-        direction, state.opt, state.trainable, lr=fc.actor_lr,
-        max_grad_norm=1.0)
-    r_w = critic_lib.r_w_bound(r_max=1.0)
-    new_critic, td_err = critic_lib.td_update(
-        state.critic, feats, r_tok, mask, fc.gamma, fc.critic_lr, r_w)
-    new_kl = kl_lib.adaptive_kl_update(state.kl_coef, metrics["kl"],
-                                       fc.kl_target)
+    with jax.named_scope("local_step/mgda"):
+        direction = firm.mgda.combine(grads, lam)
+    new_trainable, new_opt, gnorm = _adam(fc, direction, state)
+    new_critic, td_err, new_kl = _critic_kl(fc, state, feats, r_tok, mask,
+                                            metrics)
     new_state = ClientState(new_trainable, new_critic, new_opt, lam,
                             new_kl, state.step + 1)
     return new_state, dict(metrics, lam=lam, grad_norm=gnorm, td_err=td_err)

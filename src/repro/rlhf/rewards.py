@@ -119,12 +119,13 @@ def score_batch_banded(helpful: jnp.ndarray, harmful: jnp.ndarray,
     vmap over a leading client axis of (C, 2) bands scores every client's
     rollouts in one dispatch, including heterogeneous-RM sweeps.
     """
-    cols = [helpfulness_reward(tokens, mask, helpful),
-            harmlessness_reward(tokens, mask, harmful),
-            conciseness_reward(tokens, mask, length_tolerance)]
-    if n_objectives > len(cols):
-        raise ValueError(f"at most {len(cols)} synthetic objectives")
-    return jnp.stack(cols[:n_objectives], axis=-1)
+    with jax.named_scope("rewards"):
+        cols = [helpfulness_reward(tokens, mask, helpful),
+                harmlessness_reward(tokens, mask, harmful),
+                conciseness_reward(tokens, mask, length_tolerance)]
+        if n_objectives > len(cols):
+            raise ValueError(f"at most {len(cols)} synthetic objectives")
+        return jnp.stack(cols[:n_objectives], axis=-1)
 
 
 # ---------------------------------------------------------------- learned RM

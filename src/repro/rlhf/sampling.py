@@ -26,9 +26,10 @@ def generate(cfg: ModelConfig, params, prompt: jnp.ndarray, key,
     """
     b, p = prompt.shape
     total = p + max_new
-    _, cache = transformer.prefill(cfg, params, prompt, aux,
-                                   cache_len=total)
-    last = prompt[:, -1:]
+    with jax.named_scope("generate/prefill"):
+        _, cache = transformer.prefill(cfg, params, prompt, aux,
+                                       cache_len=total)
+        last = prompt[:, -1:]
 
     def step(carry, k):
         cache, tok = carry
@@ -39,15 +40,17 @@ def generate(cfg: ModelConfig, params, prompt: jnp.ndarray, key,
                                  nxt[:, None], axis=-1)[:, 0]
         return (cache, nxt[:, None]), (nxt, lp)
 
-    keys = jax.random.split(key, max_new)
-    (_, _), (new_toks, new_lps) = jax.lax.scan(step, (cache, last), keys)
-    new_toks = jnp.moveaxis(new_toks, 0, 1)                   # (B, max_new)
-    new_lps = jnp.moveaxis(new_lps, 0, 1)
-    tokens = jnp.concatenate([prompt, new_toks], axis=1)
-    logprobs = jnp.concatenate([jnp.zeros((b, p), jnp.float32), new_lps],
-                               axis=1)
-    mask = jnp.concatenate([jnp.zeros((b, p), jnp.float32),
-                            jnp.ones((b, max_new), jnp.float32)], axis=1)
+    with jax.named_scope("generate/decode"):
+        keys = jax.random.split(key, max_new)
+        (_, _), (new_toks, new_lps) = jax.lax.scan(step, (cache, last),
+                                                   keys)
+        new_toks = jnp.moveaxis(new_toks, 0, 1)               # (B, max_new)
+        new_lps = jnp.moveaxis(new_lps, 0, 1)
+        tokens = jnp.concatenate([prompt, new_toks], axis=1)
+        logprobs = jnp.concatenate(
+            [jnp.zeros((b, p), jnp.float32), new_lps], axis=1)
+        mask = jnp.concatenate([jnp.zeros((b, p), jnp.float32),
+                                jnp.ones((b, max_new), jnp.float32)], axis=1)
     return tokens, logprobs, mask
 
 
