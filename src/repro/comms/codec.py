@@ -149,6 +149,11 @@ class Codec:
     # the flat-vector transform is pure jnp (jit-safe), so the fused
     # round scan may inline encode->decode via the traced API below
     traceable = True
+    # ... and the jitted ``roundtrip_traced`` decodes bit-identically to
+    # the host boundary, so the per-round engine runs its downlink round
+    # trip as one program (codecs whose traced decode drifts keep the
+    # host path)
+    traced_matches_host = True
 
     # -- flat-vector transform (override) -------------------------------
     def encode_flat(self, flat: jnp.ndarray, *, key=None
@@ -357,15 +362,11 @@ class Codec:
             jax.lax.optimization_barrier(flats), keys)
         return arrays, jax.lax.optimization_barrier(decoded)
 
-    def stacked_payloads_from_arrays(self, arrays, c: int, spec: "TreeSpec",
-                                     d: int):
-        """Per-client Payloads from ``encode_decode_traced_stacked``'s
-        array outputs (leading (C,) axis layout; batch-shaped codecs
-        override to slice their concatenated-row layout)."""
-        meta = self.meta_static(d)
-        return [Payload(self.name, {k: v[i] for k, v in arrays.items()},
-                        {**meta, "spec": spec, "d": d})
-                for i in range(c)]
+    def split_stacked_arrays(self, arrays, c: int, d: int):
+        """Per-client wire buffers of ``encode_decode_traced_stacked``'s
+        array outputs, in-graph (leading (C,) axis layout; batch-shaped
+        codecs override to slice their concatenated-row layout)."""
+        return [{k: v[i] for k, v in arrays.items()} for i in range(c)]
 
 
 class IdentityCodec(Codec):
@@ -413,6 +414,11 @@ class ErrorFeedback(Codec):
         self.name = inner.name + "+ef"
         self._rt_flat_jit = None
         self._rt_stacked_jit = None
+        self._zeros = {}
+
+    @property
+    def traced_matches_host(self):
+        return self.inner.traced_matches_host
 
     # jitted handles are cached per codec instance (one instance serves
     # every client of a trainer, so each trainer compiles these once);
@@ -429,17 +435,29 @@ class ErrorFeedback(Codec):
         return self._rt_flat_jit
 
     def _jit_rt_stacked(self):
-        # the stacked round trip is the engine's uplink
+        # the stacked round trip is the engine's uplink: the C residual
+        # rows and keys stack, and the per-client wire buffers and new
+        # residuals split, inside this one program
         if self._rt_stacked_jit is None:
-            def fn(f, s, k):
+            def fn(f, states, keys):
                 with jax.named_scope("uplink_codec"):
-                    adj = f + s
+                    c, d = f.shape
+                    adj = f + jnp.stack(states)
                     arrays, dec = self.inner.encode_decode_traced_stacked(
-                        adj, keys=k)
-                    return arrays, dec, adj - dec
+                        adj, keys=jnp.asarray(keys))
+                    res = adj - dec
+                    return (self.inner.split_stacked_arrays(arrays, c, d),
+                            dec, [res[i] for i in range(c)])
             self._rt_stacked_jit = jitwatch.wrap(
                 "ef_roundtrip_stacked", fn, counted=False)
         return self._rt_stacked_jit
+
+    def _zero_residual(self, d: int):
+        """The residual of a client that has not uploaded yet: one zero
+        row per width, made once and passed for every such client."""
+        if d not in self._zeros:
+            self._zeros[d] = jnp.zeros((d,), jnp.float32)
+        return self._zeros[d]
 
     def encode(self, tree, state=None, *, key=None):
         flat, spec = tree_to_flat(tree)
@@ -467,22 +485,25 @@ class ErrorFeedback(Codec):
 
         Row i is bit-identical to ``roundtrip_flat(flats[i], ...,
         states[i], key=keys[i])`` — residual accumulation is elementwise,
-        so stacking commutes with it."""
-        c = flats.shape[0]
+        so stacking commutes with it.  ``keys`` is a (C, 2) key array or
+        a list of C keys; ``states`` a list of C residuals (None before
+        a client's first upload).  The host does no stacking or slicing:
+        the program returns each client's wire buffers and residual."""
+        c, d = flats.shape
         states = list(states) if states is not None else [None] * c
-        keys = list(keys) if keys is not None else [None] * c
-        if any(k is None for k in keys):
+        if keys is None or (isinstance(keys, (list, tuple))
+                            and any(k is None for k in keys)):
             # per-row base loop keeps the None-key (deterministic
             # rounding) semantics of the inner codec
             return super().roundtrip_stacked(flats, spec, states,
                                              keys=keys)
-        sts = jnp.stack([jnp.zeros_like(flats[i]) if s is None else s
-                         for i, s in enumerate(states)])
-        arrays, decoded, residual = self._jit_rt_stacked()(flats, sts,
-                                                           jnp.stack(keys))
-        payloads = self.inner.stacked_payloads_from_arrays(
-            arrays, c, spec, int(flats.shape[1]))
-        return payloads, [residual[i] for i in range(c)], decoded
+        sts = [self._zero_residual(d) if s is None else s for s in states]
+        arrays, decoded, residuals = self._jit_rt_stacked()(flats, sts,
+                                                            keys)
+        meta = {**self.inner.meta_static(d), "spec": spec, "d": d}
+        payloads = [Payload(self.inner.name, a, dict(meta))
+                    for a in arrays]
+        return payloads, list(residuals), decoded
 
     def encode_stacked(self, flats, spec, states=None, *, keys=None):
         payloads, new_states, _ = self.roundtrip_stacked(
@@ -560,6 +581,9 @@ class DeltaCodec(Codec):
     """
 
     stateful = True
+    # the in-graph reconstruction add contracts into an fma, so the traced
+    # decode matches the host boundary to 1e-6, not bit for bit
+    traced_matches_host = False
 
     def __init__(self, inner: Codec):
         self.inner = inner

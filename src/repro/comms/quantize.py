@@ -152,7 +152,7 @@ class QuantizeCodec(Codec):
         clients' blocks, bit-identical rows to per-client
         ``roundtrip_traced`` calls — and the wire buffers (int4 packed)
         returned alongside the decode, in the concatenated-row layout
-        ``stacked_payloads_from_arrays`` slices.  ``keys`` is a (C, 2)
+        ``split_stacked_arrays`` slices.  ``keys`` is a (C, 2)
         key array (stacked callers always supply per-client keys).  The
         wire boundary is marked with (best-effort) optimization barriers
         — see ``Codec.roundtrip_traced`` for what they do and do not
@@ -184,13 +184,11 @@ class QuantizeCodec(Codec):
         _, decoded = self.encode_decode_traced_stacked(flats, keys=keys)
         return decoded, states
 
-    def stacked_payloads_from_arrays(self, arrays, c, spec, d):
-        """Slice the concatenated-row codes/scales into per-client
-        Payloads — identical layout (and bytes) to per-client encodes."""
+    def split_stacked_arrays(self, arrays, c, d):
+        """Slice the concatenated-row codes/scales into per-client wire
+        buffers, in-graph — identical layout (and bytes) to per-client
+        encodes."""
         rows = -(-d // BLOCK)
-        meta = self.meta_static(d)
-        return [Payload(
-            self.name,
-            {"codes": arrays["codes"][i * rows:(i + 1) * rows],
-             "scales": arrays["scales"][i * rows:(i + 1) * rows]},
-            {**meta, "spec": spec, "d": d}) for i in range(c)]
+        return [{"codes": arrays["codes"][i * rows:(i + 1) * rows],
+                 "scales": arrays["scales"][i * rows:(i + 1) * rows]}
+                for i in range(c)]

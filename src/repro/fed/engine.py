@@ -51,7 +51,20 @@ phase through their own ``exchange_phase_vectorized`` hook.  The uplink
 codec runs at a *stacked* Payload boundary
 (``Codec.roundtrip_stacked``): quantize codecs encode all C client
 deltas in one batched kernel dispatch, byte-identical to per-client
-encodes.
+encodes; for ``+ef`` codecs that boundary is one program that stacks
+the per-client residuals and keys and returns each client's wire
+buffers and new residual, so the host neither stacks nor slices.
+
+On the per-round vectorized path every array operation of a round runs
+inside a named program (``repro.obs.jitwatch``), with no eager op
+between them: the downlink round trip is one program that also draws
+the round's first key; ``vec_round`` draws the K x P generation keys
+and then the P uplink keys from the main stream (the order sequential
+``_next_key`` calls give, so the loop, cohort, fused and scheduler
+paths consume the same stream), gathers the participants' inputs,
+advances the prompt cursors it keeps on the device and reduces the
+summary means.  The aggregation's zero staleness and exponent are made
+once.
 
 Participation sampling draws from a NAMED PRNG stream keyed on
 (seed, round index), independent of how many keys generation / codecs
@@ -92,6 +105,7 @@ from typing import List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.comms import make_codec
 from repro.comms import codec as codec_lib
@@ -174,17 +188,105 @@ def _make_round_fn(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
     return round_fn
 
 
+def _split_next(rng):
+    """In-graph twin of ``FederatedTrainer._next_key``."""
+    out = jax.random.split(rng)
+    return out[0], out[1]
+
+
+def _draw_keys(rng, rows: int, cols: int):
+    """``rows x cols`` sequential ``_split_next`` draws, row-major:
+    (advanced rng, (rows, cols, 2) keys)."""
+    out = []
+    for _ in range(rows):
+        row = []
+        for _ in range(cols):
+            rng, k = _split_next(rng)
+            row.append(k)
+        out.append(jnp.stack(row))
+    return rng, jnp.stack(out)
+
+
 @functools.lru_cache(maxsize=None)
 def _jit_vec_round(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
                    prompt_len: int, max_new: int, length_tol: int,
                    has_pref: bool):
     """The per-round dispatch of ``_make_round_fn`` (stacked state
-    donated)."""
-    return jitwatch.wrap(
-        f"vec_round[{kernel}]",
-        _make_round_fn(cfg, cfc, kernel, prompt_len, max_new, length_tol,
-                       has_pref),
-        donate_argnums=(0,))
+    donated), with the round's array bookkeeping inside the program.
+
+    ``keys`` is either the (K, P, 2) generation keys (the cohort path
+    draws them across cohorts) or the main stream's key: then the
+    program draws the K x P generation keys step-major and the P uplink
+    keys after them, exactly as sequential ``_next_key`` calls would, and
+    returns the uplink keys and the advanced stream key.  With ``idx``
+    (the participants, when they are a strict subset) the per-client
+    inputs are the whole population's and are gathered here; ``counts``
+    comes back advanced by K for the participants.  The staged summary
+    means are computed here too (one axis at a time, as in the fused
+    round scan, so the two paths stay bit-identical).
+    """
+    round_fn = _make_round_fn(cfg, cfc, kernel, prompt_len, max_new,
+                              length_tol, has_pref)
+    k_steps = cfc.local_steps
+
+    def vec_round(state, frozen, ref_params, seeds, counts, probs, band_h,
+                  band_x, keys, pref, extra, idx=None):
+        counts0 = counts
+        if idx is not None:
+            seeds, counts0, probs = seeds[idx], counts[idx], probs[idx]
+            band_h, band_x = band_h[idx], band_x[idx]
+            pref = pref[idx] if has_pref else None
+        rng = up_keys = None
+        if keys.ndim == 1:
+            with jax.named_scope("keys"):
+                n_part = counts0.shape[0]
+                rng, gen_keys = _draw_keys(keys, k_steps, n_part)
+                rng, up_keys = _draw_keys(rng, 1, n_part)
+                up_keys = up_keys[0]
+        else:
+            gen_keys = keys
+        final, ms = round_fn(state, frozen, ref_params, seeds, counts0,
+                             probs, band_h, band_x, gen_keys, pref, extra)
+        with jax.named_scope("summary"):
+            stats = (ms["lam"][-1],                            # (P, M)
+                     ms["rewards"].mean(0).mean(0),
+                     ms["kl"].mean(0).mean(0),
+                     ms["rewards"].mean(0))                    # (P, M)
+        counts = (counts + k_steps if idx is None
+                  else counts.at[idx].add(k_steps))
+        return final, stats, up_keys, rng, counts
+
+    return jitwatch.wrap(f"vec_round[{kernel}]", vec_round,
+                         donate_argnums=(0,))
+
+
+@functools.lru_cache(maxsize=None)
+def _jit_downlink(downlink_spec: str):
+    """The downlink broadcast as one program: the stream's next key, the
+    codec's round trip of the flattened tree (with the state update of a
+    stateful codec, host-format state in and out) and the unflatten."""
+    codec = make_codec(downlink_spec)
+
+    def downlink(rng, tree, state):
+        with jax.named_scope("downlink_codec"):
+            rng, key = _split_next(rng)
+            flat, spec = codec_lib.tree_to_flat(tree)
+            dec, state = codec.roundtrip_traced(
+                flat, codec.init_state_traced(flat.size, state), key=key)
+            return (rng, codec_lib.flat_to_tree(dec, spec),
+                    codec.state_to_host(state))
+
+    return jitwatch.wrap("downlink_roundtrip", downlink, counted=False)
+
+
+@functools.lru_cache(maxsize=None)
+def _jit_participants(n_clients: int, n: int):
+    """The round's participants from the named stream, as one program."""
+    def participants(base, round_idx):
+        with jax.named_scope("participants"):
+            return jax.random.choice(jax.random.fold_in(base, round_idx),
+                                     n_clients, (n,), replace=False)
+    return jitwatch.wrap("participants", participants, counted=False)
 
 
 @functools.lru_cache(maxsize=None)
@@ -230,6 +332,18 @@ def _jit_flat_aggregate(spec):
     return jitwatch.wrap("flat_aggregate", fn)
 
 
+# operands the aggregation takes every round, made once: the synchronous
+# round's zero staleness and the staleness exponent
+@functools.lru_cache(maxsize=None)
+def _zeros_f32(n: int):
+    return jnp.zeros(n, jnp.float32)
+
+
+@functools.lru_cache(maxsize=None)
+def _f32(x: float):
+    return jnp.float32(x)
+
+
 def _summary_device_fn(lams, rewards_mean, kl_mean, stacked_trainable,
                        rewards_pc):
     """All round-summary statistics computed device-side; the engine does
@@ -257,6 +371,9 @@ class LocalPhaseResult(NamedTuple):
     kl_mean: jnp.ndarray             # scalar
     stacked_trainable: object        # pytree with leading (P,) client axis
     rewards_pc: jnp.ndarray          # (P, M) per-client mean over steps
+    # (P, 2) uplink keys, when the phase drew them in-graph after the
+    # generation keys; None when the caller draws them with _next_key
+    up_keys: Optional[jnp.ndarray] = None
 
 
 class FusedCarry(NamedTuple):
@@ -287,12 +404,6 @@ class FusedCarry(NamedTuple):
     dl_state: object
     counts: jnp.ndarray
     rng: jnp.ndarray
-
-
-def _split_next(rng):
-    """In-graph twin of ``FederatedTrainer._next_key``."""
-    out = jax.random.split(rng)
-    return out[0], out[1]
 
 
 @functools.lru_cache(maxsize=None)
@@ -521,6 +632,9 @@ class FederatedTrainer:
         # last round's uplink payloads (per-round path only; offline
         # payload analysis, e.g. entropy estimates in codec_tradeoff)
         self._last_up_payloads: List = []
+        # (host prompt cursors, their device copy), reused while the two
+        # agree instead of being sent again each round
+        self._counts = (None, None)
         # the declarative mirror of this trainer's path decisions; built
         # through the same capability resolution the methods below use
         self.plan = plan if plan is not None else api_lib.plan(
@@ -549,10 +663,6 @@ class FederatedTrainer:
         return ppo.PPOBatch(tokens, mask, old_lp, ref_lp, r)
 
     # ------------------------------------------------------------------
-    def _participation_key(self, round_idx: Optional[int] = None):
-        r = self._round_idx if round_idx is None else round_idx
-        return jax.random.fold_in(self._part_rng_base, r)
-
     def _sample_participants(self, n: Optional[int] = None,
                              round_idx: Optional[int] = None) -> List[int]:
         """Draw this round's participants from the named stream.
@@ -566,9 +676,9 @@ class FederatedTrainer:
             n = max(1, int(round(fc.participation * fc.n_clients)))
         if n >= fc.n_clients:
             return list(range(fc.n_clients))
-        idx = jax.random.choice(self._participation_key(round_idx),
-                                fc.n_clients, (n,), replace=False)
-        return sorted(int(i) for i in idx)
+        r = self._round_idx if round_idx is None else round_idx
+        idx = _jit_participants(fc.n_clients, n)(self._part_rng_base, r)
+        return sorted(np.asarray(idx).tolist())
 
     def _local_phase_mode(self, participants: List[int]):
         """Pick the round's local-phase path: ("vec"|"cohort"|"loop", plan).
@@ -606,9 +716,21 @@ class FederatedTrainer:
         scheduler calls this directly with nonzero staleness."""
         out = _jit_flat_aggregate(self._delta_spec)(
             anchor, flats, jnp.asarray(staleness, jnp.float32),
-            jnp.float32(staleness_pow))
+            _f32(staleness_pow))
         self.jit_dispatches += 1
         return out
+
+    def _prompt_counts(self):
+        """Every client's prompt-stream cursor on the device, (C,) int32.
+
+        The vectorized round program returns the advanced cursors, so
+        while the host cursors match the ones it returned last, they are
+        not sent again; any other path that moves a cursor (the loop, a
+        fused chunk, a host-exchange algorithm) makes them differ."""
+        host = tuple(ds._count for ds in self.datasets)
+        if self._counts[0] != host:
+            self._counts = (host, jnp.asarray(np.asarray(host, np.int32)))
+        return self._counts[1]
 
     def run_round(self, participants: Optional[List[int]] = None) -> dict:
         # the host phases are spans on the profiler's clock while a JAX
@@ -625,14 +747,9 @@ class FederatedTrainer:
         dispatch0 = self.jit_dispatches
         # broadcast θ_t through the downlink codec; every client receives
         # (and trains from) the same decoded broadcast
-        with jitwatch.span("round/keys"):
-            dl_key = self._next_key()
         with jitwatch.span("round/downlink"):
-            dl_payload, self._downlink_state, broadcast = \
-                self.downlink_codec.roundtrip(
-                    self.global_trainable, self._downlink_state, key=dl_key)
-            for c in participants:
-                self.ledger.send_down(dl_payload)
+            broadcast, down_nbytes = self._downlink()
+            self.ledger.down_bytes += len(participants) * down_nbytes
 
         with jitwatch.span("round/local_phase"):
             mode, plan = self._local_phase_mode(participants)
@@ -652,14 +769,16 @@ class FederatedTrainer:
         # uplink codec (residuals stay client-local); the delta against
         # the broadcast anchor flattens in one batched tree op over the
         # stacked axis, the codec encodes all clients at the stacked
-        # (flat) Payload boundary — one batched kernel dispatch for
-        # quantize codecs — and the server aggregates the decoded (C, d)
-        # matrix in one matvec + single unflatten
+        # (flat) Payload boundary — one program for the error-feedback
+        # codecs — and the server aggregates the decoded (C, d) matrix in
+        # one matvec + single unflatten
         with jitwatch.span("round/uplink"):
             flat_deltas = _delta_flat_jit(res.stacked_trainable, broadcast)
             self.jit_dispatches += 1
-            with jitwatch.span("round/keys"):
-                up_keys = [self._next_key() for _ in participants]
+            up_keys = res.up_keys
+            if up_keys is None:
+                with jitwatch.span("round/keys"):
+                    up_keys = [self._next_key() for _ in participants]
             payloads, new_states, decoded = \
                 self.uplink_codec.roundtrip_stacked(
                     flat_deltas, self._delta_spec,
@@ -673,8 +792,7 @@ class FederatedTrainer:
         self._last_up_payloads = payloads
         with jitwatch.span("round/aggregate"):
             self.global_trainable = self._aggregate_flat(
-                broadcast, decoded,
-                jnp.zeros(len(participants), jnp.float32))
+                broadcast, decoded, _zeros_f32(len(participants)))
         self.ledger.next_round()
         self._round_idx += 1
 
@@ -695,7 +813,7 @@ class FederatedTrainer:
             dispatches=self.jit_dispatches - dispatch0,
             # per-client wire/work facts the scheduler's time model reads
             up_nbytes=[int(p.nbytes) for p in payloads],
-            down_nbytes=comms.measured_bytes(dl_payload),
+            down_nbytes=down_nbytes,
             local_steps=[self._client_fcs[c].local_steps
                          for c in participants],
             cohorts=len(plan) if plan is not None else 0,
@@ -703,6 +821,23 @@ class FederatedTrainer:
         self.history.append(summary)
         self.obs.emit_round(summary, round=round_idx)
         return summary
+
+    def _downlink(self):
+        """θ_t through the downlink codec: (decoded broadcast, wire bytes
+        per receiver).  A codec whose traced round trip matches its host
+        round trip runs as one program that also draws the stream's next
+        key; any other codec draws the key and round-trips on the host."""
+        codec = self.downlink_codec
+        if codec.traced_matches_host:
+            self._rng, broadcast, self._downlink_state = _jit_downlink(
+                self.ec.downlink_codec)(self._rng, self.global_trainable,
+                                        self._downlink_state)
+            return broadcast, codec.nbytes_static(self.d_trainable)
+        with jitwatch.span("round/keys"):
+            key = self._next_key()
+        payload, self._downlink_state, broadcast = codec.roundtrip(
+            self.global_trainable, self._downlink_state, key=key)
+        return broadcast, comms.measured_bytes(payload)
 
     # ------------------------------------------------- fused rounds path
     def run_rounds_fused(self, rounds: int) -> List[dict]:
@@ -850,24 +985,16 @@ class FederatedTrainer:
         ``gen_keys`` optionally supplies pre-drawn (K, C, 2) generation
         keys — the multi-cohort dispatch draws them in the canonical
         loop order across ALL participants and slices per cohort.
+        Without them the round program draws the generation keys and
+        then the participants' uplink keys (``LocalPhaseResult.up_keys``)
+        from the main stream.
         """
         p_count = len(participants)
         k_steps = fc.local_steps
-        m = fc.n_objectives
         has_pref = self._stacked_pref is not None
         cfc = dataclasses.replace(fc, preference=None) if has_pref else fc
-
-        counts0 = jnp.asarray([self.datasets[c]._count
-                               for c in participants], jnp.int32)
-        if p_count == self.fc.n_clients:     # full participation: cached
-            seeds, probs = self._seeds_all, self._probs_all
-            band_h, band_x = self._bands_h, self._bands_x
-            pref = self._stacked_pref if has_pref else None
-        else:
-            idx = jnp.asarray(participants, jnp.int32)
-            seeds, probs = self._seeds_all[idx], self._probs_all[idx]
-            band_h, band_x = self._bands_h[idx], self._bands_x[idx]
-            pref = self._stacked_pref[idx] if has_pref else None
+        full = p_count == self.fc.n_clients
+        counts = self._prompt_counts()
         # advance the per-client prompt streams exactly as the loop would
         for c in participants:
             self.datasets[c]._count += k_steps
@@ -879,45 +1006,49 @@ class FederatedTrainer:
         stacked = _stack_trees_jit(*states)
         self.jit_dispatches += 1
 
+        up_keys = None
         if not self.algorithm.caps.traced_server_exchange:
             # host-driven server exchange: the algorithm owns the phase
             # (jitted client phases around its host exchange)
+            if full:
+                seeds, probs = self._seeds_all, self._probs_all
+                band_h, band_x = self._bands_h, self._bands_x
+            else:
+                idx = jnp.asarray(participants, jnp.int32)
+                seeds, probs = self._seeds_all[idx], self._probs_all[idx]
+                band_h, band_x = self._bands_h[idx], self._bands_x[idx]
+                counts = counts[idx]
             lams, rewards_mean, kl_mean, rewards_pc, stacked = \
                 self.algorithm.exchange_phase_vectorized(
-                    self, cfc, participants, stacked, seeds, counts0,
+                    self, cfc, participants, stacked, seeds, counts,
                     probs, band_h, band_x)
         else:
-            if gen_keys is None:
-                # per-client generation keys, drawn in the loop path's
-                # order (step-major, then participant order) for exact
-                # key parity
-                with jitwatch.span("round/keys"):
-                    gen_keys = jnp.stack(
-                        [jnp.stack([self._next_key() for _ in participants])
-                         for _ in range(k_steps)])
+            # without pre-drawn keys the program draws the generation
+            # keys, then the uplink keys, from the main stream in the
+            # loop path's order (step-major, then participant order)
             extra = self.algorithm.traced_extra(cfc, self.ec)
             fn = _jit_vec_round(self.cfg, cfc, self.algorithm.kernel,
                                 self.ec.prompt_len, self.ec.max_new,
                                 self._length_tol, has_pref)
-            stacked, ms = fn(stacked, self.frozen, self.ref_params, seeds,
-                             counts0, probs, band_h, band_x, gen_keys,
-                             pref, extra)
+            stacked, stats, up_keys, rng, counts = fn(
+                stacked, self.frozen, self.ref_params, self._seeds_all,
+                counts, self._probs_all, self._bands_h, self._bands_x,
+                self._rng if gen_keys is None else gen_keys,
+                self._stacked_pref, extra,
+                None if full else np.asarray(participants, np.int32))
             self.jit_dispatches += 1
-            lams = ms["lam"][-1]                              # (C, M)
-            # one axis at a time: a flat (K*C) mean is emitted as a
-            # multi-dim reduce whose association differs between this
-            # eager context and the fused round scan; staged means are
-            # context-stable, keeping the two paths bit-identical
-            rewards_mean = ms["rewards"].mean(0).mean(0)
-            kl_mean = ms["kl"].mean(0).mean(0)
-            rewards_pc = ms["rewards"].mean(0)                # (C, M)
+            lams, rewards_mean, kl_mean, rewards_pc = stats
+            if rng is not None:
+                self._rng = rng
+            self._counts = (tuple(ds._count for ds in self.datasets),
+                            counts)
 
         new_states = _jit_unstack(p_count)(stacked)
         self.jit_dispatches += 1
         for ci, c in enumerate(participants):
             self.client_states[c] = new_states[ci]
         return LocalPhaseResult(lams, rewards_mean, kl_mean,
-                                stacked.trainable, rewards_pc)
+                                stacked.trainable, rewards_pc, up_keys)
 
     # ------------------------------------------------- cohort dispatch
     def _local_phase_cohorts(self, plan, participants: List[int],

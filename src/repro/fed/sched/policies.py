@@ -340,10 +340,13 @@ class FedBuffPolicy:
                                                broadcast)
             tr.jit_dispatches += 1
             for i, c in enumerate(members):
+                # the vectorized phase drew the members' uplink keys
+                # after their generation keys, as _next_key would
                 payload, tr._uplink_state[c], dec = \
                     tr.uplink_codec.roundtrip_flat(
                         flats[i], tr._delta_spec, tr._uplink_state[c],
-                        key=tr._next_key())
+                        key=(tr._next_key() if res.up_keys is None
+                             else res.up_keys[i]))
                 tr.ledger.send_up(payload)
                 segs = st.client_segments(c, down_nbytes, payload.nbytes,
                                           co.cfc.local_steps)

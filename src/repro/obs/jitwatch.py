@@ -43,10 +43,10 @@ import jax
 # The round's layers: one jax.named_scope each, placed around the code
 # that does the work (sampling, engine, codec).
 LAYERS = (
-    "sample_prompts", "generate/prefill", "generate/decode", "rewards",
-    "ref_forward", "local_step/grads", "local_step/mgda",
-    "local_step/adam", "local_step/critic_kl", "delta", "uplink_codec",
-    "aggregate", "summary",
+    "participants", "keys", "downlink_codec", "sample_prompts",
+    "generate/prefill", "generate/decode", "rewards", "ref_forward",
+    "local_step/grads", "local_step/mgda", "local_step/adam",
+    "local_step/critic_kl", "delta", "uplink_codec", "aggregate", "summary",
 )
 
 

@@ -13,16 +13,19 @@ from repro.configs.base import FIRMConfig
 from repro.fed.engine import EngineConfig, FederatedTrainer
 from repro.obs import jitwatch
 
-ROUND_PROGRAMS = {"stack_trees", "vec_round[firm]", "unstack", "delta_flat",
-                  "ef_roundtrip_stacked", "flat_aggregate", "summary_device"}
-ROUND_PHASES = {"round", "round/keys", "round/downlink", "round/local_phase",
+ROUND_PROGRAMS = {"downlink_roundtrip", "stack_trees", "vec_round[firm]",
+                  "unstack", "delta_flat", "ef_roundtrip_stacked",
+                  "flat_aggregate", "summary_device"}
+# the round's keys are drawn inside its programs, so no round/keys span
+ROUND_PHASES = {"round", "round/downlink", "round/local_phase",
                 "round/uplink", "round/aggregate", "round/summary"}
 # the layers inside the per-client local phase
 LOCAL_LAYERS = {"sample_prompts", "generate/prefill", "generate/decode",
                 "rewards", "ref_forward", "local_step/grads",
                 "local_step/mgda", "local_step/adam", "local_step/critic_kl"}
 # the layers that are whole programs on the per-round path
-PROGRAM_LAYERS = {"delta_flat": "delta",
+PROGRAM_LAYERS = {"downlink_roundtrip": "downlink_codec",
+                  "delta_flat": "delta",
                   "ef_roundtrip_stacked": "uplink_codec",
                   "flat_aggregate": "aggregate",
                   "summary_device": "summary"}
@@ -98,7 +101,9 @@ def test_one_rounds_programs_have_distinct_module_names(traced, round_map):
 def test_round_program_leaves_map_to_one_layer_each(round_map):
     pm, = [p for p in round_map.values() if p.name == "vec_round[firm]"]
     layers = set(pm.ops.values())
-    assert layers - {None} == LOCAL_LAYERS
+    # besides the local phase, the program draws the round's keys and
+    # reduces the summary's means
+    assert layers - {None} == LOCAL_LAYERS | {"keys", "summary"}
     attributed = sum(1 for v in pm.ops.values() if v is not None)
     # XLA's CPU backend adds bf16 converts without a name stack that no
     # layer uses alone; the chip's share is measured on the trace
