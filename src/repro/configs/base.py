@@ -30,7 +30,6 @@ from typing import Optional, Tuple
 class MoEConfig:
     n_experts: int
     top_k: int
-    capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
 
 
@@ -91,6 +90,10 @@ class ModelConfig:
 
     # ------------------------------------------------------------------ derived
     def __post_init__(self):
+        # a configuration file gives ``moe`` as a plain dict; the config
+        # must stay hashable (it is a static argument of the programs)
+        if isinstance(self.moe, dict):
+            object.__setattr__(self, "moe", MoEConfig(**self.moe))
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         if self.n_periods == 0:

@@ -1,7 +1,9 @@
-"""mixtral-8x7b [moe] — 8 experts top-2, sliding-window attention.
+"""mixtral-8x7b [moe] — 8 experts top-2 in every layer, full attention.
 
-32L d_model=4096 32H (GQA kv=8) d_ff=14336 vocab=32000.  [arXiv:2401.04088]
-SWA(4096) makes decode sub-quadratic -> eligible for long_500k.
+32L d_model=4096 32H (GQA kv=8) head_dim 128 d_ff=14336 vocab=32000,
+RoPE theta 1e6, RMSNorm eps 1e-5, untied head.  ``config.json`` has
+``"sliding_window": null``: every layer attends to the whole prefix.
+[https://huggingface.co/mistralai/Mixtral-8x7B-v0.1, arXiv:2401.04088]
 """
 from repro.configs.base import ModelConfig, MoEConfig
 
@@ -14,11 +16,10 @@ CONFIG = ModelConfig(
     n_kv_heads=8,
     d_ff=14336,
     vocab=32000,
-    pattern=("moe_swa",),
+    pattern=("moe",),
     n_periods=32,
     rope_theta=1000000.0,
-    sliding_window=4096,
     moe=MoEConfig(n_experts=8, top_k=2),
-    source="arXiv:2401.04088",
-    subquadratic=True,
+    source="https://huggingface.co/mistralai/Mixtral-8x7B-v0.1",
+    subquadratic=False,
 )

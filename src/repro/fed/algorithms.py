@@ -275,7 +275,9 @@ def _jit_vec_fedcmoo_grads(cfg, cfc: FIRMConfig, max_new: int,
     boundary between the two jitted phases."""
     m = cfc.n_objectives
 
-    def fn(state, frozen, ref_params, prompts, keys, band_h, band_x):
+    def fn(state, frozen, ref_trainable, prompts, keys, band_h, band_x):
+        ref_params = merge_trainable(ref_trainable, frozen)
+
         def one(st, pr, key, bh, bx):
             params = merge_trainable(st.trainable, frozen)
             tokens, old_lp, mask = generate(cfg, params, pr, key,
@@ -411,7 +413,7 @@ class FedCMOOAlgorithm(Algorithm):
             prompts = sampler(seeds, counts0 + k, probs)
             tr.jit_dispatches += 1
             grads, extras, rmean = grads_fn(
-                stacked, tr.frozen, tr.ref_params, prompts,
+                stacked, tr.frozen, tr.ref_trainable, prompts,
                 jnp.stack(kb), band_h, band_x)
             tr.jit_dispatches += 1
             # (C, M, d) client-major rows match the loop path's upload

@@ -115,7 +115,7 @@ from repro.data.partition import make_client_datasets, sample_prompt_block
 from repro.fed import api as api_lib
 from repro.fed.algorithms import client_configs, get_algorithm
 from repro.fed.api import EngineConfig  # noqa: F401  (canonical home is api)
-from repro.models import transformer
+from repro.models import moe as moe_lib, transformer
 from repro.models.common import merge_trainable, split_trainable, tree_size
 from repro.obs import jitwatch
 from repro.obs import records as obs_records
@@ -153,8 +153,11 @@ def _make_round_fn(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
     m = cfc.n_objectives
     b = cfc.batch_size
 
-    def round_fn(state, frozen, ref_params, seeds, counts0, probs,
+    def round_fn(state, frozen, ref_trainable, seeds, counts0, probs,
                  band_h, band_x, gen_keys, pref, extra):
+        # the frozen reference is the base with the initial adapters: the
+        # base enters the program once, not a second time as the reference
+        ref_params = merge_trainable(ref_trainable, frozen)
 
         def one_client(st, prompts, key, bh, bx, p):
             params = merge_trainable(st.trainable, frozen)
@@ -178,7 +181,8 @@ def _make_round_fn(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
                                               probs, b, prompt_len, cfg.vocab)
             new_state, metrics = vstep(carry, prompts, keys_c, band_h,
                                        band_x, pref)
-            keep = {k: metrics[k] for k in ("lam", "rewards", "kl")}
+            keep = {k: metrics[k] for k in ("lam", "rewards", "kl",
+                                            "moe_counts") if k in metrics}
             return new_state, keep
 
         final, ms = jax.lax.scan(body, state,
@@ -186,6 +190,16 @@ def _make_round_fn(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
         return final, ms
 
     return round_fn
+
+
+def _moe_max_load(ms):
+    """The round's ``moe_max_load`` from the local phase's stacked
+    metrics: routed pairs of the policy forward summed over the K steps
+    and the clients, per layer and expert (``moe.max_load``); None for a
+    model without expert blocks."""
+    if "moe_counts" not in ms:
+        return None
+    return moe_lib.max_load(ms["moe_counts"].sum((0, 1)))
 
 
 def _split_next(rng):
@@ -229,8 +243,8 @@ def _jit_vec_round(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
                               length_tol, has_pref)
     k_steps = cfc.local_steps
 
-    def vec_round(state, frozen, ref_params, seeds, counts, probs, band_h,
-                  band_x, keys, pref, extra, idx=None):
+    def vec_round(state, frozen, ref_trainable, seeds, counts, probs,
+                  band_h, band_x, keys, pref, extra, idx=None):
         counts0 = counts
         if idx is not None:
             seeds, counts0, probs = seeds[idx], counts[idx], probs[idx]
@@ -245,13 +259,14 @@ def _jit_vec_round(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
                 up_keys = up_keys[0]
         else:
             gen_keys = keys
-        final, ms = round_fn(state, frozen, ref_params, seeds, counts0,
+        final, ms = round_fn(state, frozen, ref_trainable, seeds, counts0,
                              probs, band_h, band_x, gen_keys, pref, extra)
         with jax.named_scope("summary"):
             stats = (ms["lam"][-1],                            # (P, M)
                      ms["rewards"].mean(0).mean(0),
                      ms["kl"].mean(0).mean(0),
-                     ms["rewards"].mean(0))                    # (P, M)
+                     ms["rewards"].mean(0),                    # (P, M)
+                     _moe_max_load(ms))
         counts = (counts + k_steps if idx is None
                   else counts.at[idx].add(k_steps))
         return final, stats, up_keys, rng, counts
@@ -345,11 +360,11 @@ def _f32(x: float):
 
 
 def _summary_device_fn(lams, rewards_mean, kl_mean, stacked_trainable,
-                       rewards_pc):
+                       rewards_pc, moe_max_load=None):
     """All round-summary statistics computed device-side; the engine does
     ONE host transfer per round (jax.device_get of this dict)."""
     with jax.named_scope("summary"):
-        return {
+        out = {
             "rewards": rewards_mean,
             "lam_mean": lams.mean(0),
             "lam_disagreement":
@@ -359,6 +374,9 @@ def _summary_device_fn(lams, rewards_mean, kl_mean, stacked_trainable,
             "per_client_lam": lams,
             "rewards_per_client": rewards_pc,
         }
+        if moe_max_load is not None:
+            out["moe_max_load"] = moe_max_load
+        return out
 
 
 _summary_device = jitwatch.wrap("summary_device", _summary_device_fn)
@@ -374,6 +392,9 @@ class LocalPhaseResult(NamedTuple):
     # (P, 2) uplink keys, when the phase drew them in-graph after the
     # generation keys; None when the caller draws them with _next_key
     up_keys: Optional[jnp.ndarray] = None
+    # the policy forward's expert load (``_moe_max_load``), where the
+    # phase's program measured it
+    moe_max_load: Optional[jnp.ndarray] = None
 
 
 class FusedCarry(NamedTuple):
@@ -396,7 +417,7 @@ class FusedCarry(NamedTuple):
                 per-round path
 
     The server parameters are carried too but enter the jit as a
-    NON-donated argument: at trainer init they alias ``ref_params``
+    NON-donated argument: at trainer init they alias ``ref_trainable``
     leaves, which must survive the call.
     """
     states: object
@@ -430,7 +451,7 @@ def _jit_fused_rounds(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
     k_steps = cfc.local_steps
     full = n_part >= n_clients
 
-    def fused(carry, global_tr, round_idxs, part_base, frozen, ref_params,
+    def fused(carry, global_tr, round_idxs, part_base, frozen, ref_trainable,
               seeds_all, probs_all, band_h_all, band_x_all, pref_all,
               extra):
 
@@ -482,7 +503,7 @@ def _jit_fused_rounds(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
                 gks.append(jnp.stack(row))
             gen_keys = jnp.stack(gks)
 
-            new_part, ms = round_fn(part_states, frozen, ref_params,
+            new_part, ms = round_fn(part_states, frozen, ref_trainable,
                                     seeds, counts0, probs, band_h,
                                     band_x, gen_keys, pref, extra)
 
@@ -529,6 +550,8 @@ def _jit_fused_rounds(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
                     "rewards_per_client": ms["rewards"].mean(0),
                     "participants": idx,
                 }
+                if "moe_counts" in ms:
+                    ys["moe_max_load"] = _moe_max_load(ms)
             return (states, g_tree, ul_state, dl_state, counts, rng), ys
 
         init = (carry.states, global_tr, carry.ul_state, carry.dl_state,
@@ -560,6 +583,9 @@ class FederatedTrainer:
         trainable, frozen = split_trainable(self.params)
         self.frozen = frozen
         self.ref_params = self.params                     # frozen reference
+        # ... and its adapters, which the round programs merge with the
+        # frozen base they already take
+        self.ref_trainable = trainable
         self.global_trainable = trainable
         self.client_states = [
             local_lib.init_client_state(trainable, fc.n_objectives,
@@ -800,7 +826,7 @@ class FederatedTrainer:
         with jitwatch.span("round/summary"):
             stats = _summary_device(res.lams, res.rewards_mean,
                                     res.kl_mean, res.stacked_trainable,
-                                    res.rewards_pc)
+                                    res.rewards_pc, res.moe_max_load)
             self.jit_dispatches += 1
             host = jax.device_get(stats)
         self.host_transfers += 1
@@ -889,7 +915,8 @@ class FederatedTrainer:
                                self._delta_spec, c_all, n_part)
         carry, new_global, ys = fn(
             carry, self.global_trainable, round_idxs, self._part_rng_base,
-            self.frozen, self.ref_params, self._seeds_all, self._probs_all,
+            self.frozen, self.ref_trainable, self._seeds_all,
+            self._probs_all,
             self._bands_h, self._bands_x, self._stacked_pref, extra)
         self.jit_dispatches += 1
 
@@ -926,7 +953,8 @@ class FederatedTrainer:
                 stats={k: ys_h[k][r] for k in
                        ("rewards", "lam_mean", "lam_disagreement",
                         "param_drift", "kl", "per_client_lam",
-                        "rewards_per_client")},
+                        "rewards_per_client", "moe_max_load")
+                       if k in ys_h},
                 comm_bytes=self.ledger.total,
                 up_bytes=self.ledger.up_bytes,
                 down_bytes=self.ledger.down_bytes,
@@ -1006,7 +1034,7 @@ class FederatedTrainer:
         stacked = _stack_trees_jit(*states)
         self.jit_dispatches += 1
 
-        up_keys = None
+        up_keys = moe_load = None
         if not self.algorithm.caps.traced_server_exchange:
             # host-driven server exchange: the algorithm owns the phase
             # (jitted client phases around its host exchange)
@@ -1031,13 +1059,13 @@ class FederatedTrainer:
                                 self.ec.prompt_len, self.ec.max_new,
                                 self._length_tol, has_pref)
             stacked, stats, up_keys, rng, counts = fn(
-                stacked, self.frozen, self.ref_params, self._seeds_all,
+                stacked, self.frozen, self.ref_trainable, self._seeds_all,
                 counts, self._probs_all, self._bands_h, self._bands_x,
                 self._rng if gen_keys is None else gen_keys,
                 self._stacked_pref, extra,
                 None if full else np.asarray(participants, np.int32))
             self.jit_dispatches += 1
-            lams, rewards_mean, kl_mean, rewards_pc = stats
+            lams, rewards_mean, kl_mean, rewards_pc, moe_load = stats
             if rng is not None:
                 self._rng = rng
             self._counts = (tuple(ds._count for ds in self.datasets),
@@ -1048,7 +1076,8 @@ class FederatedTrainer:
         for ci, c in enumerate(participants):
             self.client_states[c] = new_states[ci]
         return LocalPhaseResult(lams, rewards_mean, kl_mean,
-                                stacked.trainable, rewards_pc, up_keys)
+                                stacked.trainable, rewards_pc, up_keys,
+                                moe_load)
 
     # ------------------------------------------------- cohort dispatch
     def _local_phase_cohorts(self, plan, participants: List[int],
@@ -1074,7 +1103,7 @@ class FederatedTrainer:
         pos = {c: i for i, c in enumerate(participants)}
         lam_rows = [None] * len(participants)
         rpc_rows = [None] * len(participants)
-        stacked_parts, order = [], []
+        stacked_parts, order, loads = [], [], []
         rew_acc, kl_acc, w_tot = 0.0, 0.0, 0
         for co in plan:
             members = list(co.members)
@@ -1092,14 +1121,19 @@ class FederatedTrainer:
             w_tot += w
             stacked_parts.append(res.stacked_trainable)
             order.extend(members)
+            if res.moe_max_load is not None:
+                loads.append(res.moe_max_load)
 
         inv = jnp.asarray([order.index(c) for c in participants], jnp.int32)
         stacked_tr = jax.tree_util.tree_map(
             lambda *xs: jnp.concatenate(xs, axis=0)[inv], *stacked_parts)
         self.jit_dispatches += 1
+        # the most uneven cohort's load
         return LocalPhaseResult(jnp.stack(lam_rows), rew_acc / w_tot,
                                 kl_acc / w_tot, stacked_tr,
-                                jnp.stack(rpc_rows))
+                                jnp.stack(rpc_rows),
+                                moe_max_load=(jnp.max(jnp.stack(loads))
+                                              if loads else None))
 
     def run(self, rounds: Optional[int] = None) -> List[dict]:
         total = rounds or self.fc.rounds

@@ -1,20 +1,36 @@
-"""Mixture-of-Experts FFN — GShard einsum dispatch (capacity + dropping).
+"""Mixture-of-Experts FFN: top-k softmax routing, dropless, einsum dispatch.
 
-Routing builds a (S*k, E, cap) one-hot dispatch tensor per batch row and
-moves tokens with einsums only:
+The router is ``softmax(x W_g)`` in float32; each token takes its top-k
+experts and renormalises their gates over those k (Mixtral,
+arXiv:2401.04088, eq. 1-2).  Every routed (token, expert) pair is
+computed: there is no capacity and no token is dropped.
 
-  buf  = einsum('bsec,bsd->becd', dispatch, x)      # tokens -> expert rows
-  y    = einsum('bsec,becd->bsd', combine,  out)    # expert rows -> tokens
+Tokens move with einsums only (GShard's dispatch / combine form), with
+each row's capacity equal to its token count.  A token picks an expert
+at most once, so its slot in an expert's buffer is its own position and
+no pair can overflow:
 
-Why einsums: every op in both directions is a dot, so GSPMD partitions
-forward AND backward cleanly (batch on 'data', expert/d_ff on 'model').
-The earlier sort+scatter formulation was measured at 40 TB/device/step of
-involuntary all-reduce on mixtral-8x22b train_4k — GSPMD cannot keep the
-batch dim sharded through batched scatters (EXPERIMENTS §Perf hillclimb
-#2).  Dispatch-einsum overhead is ~8% of expert-FFN FLOPs at E=8, k=2.
+  buf = einsum('bse,bsd->besd', dispatch, x)     # tokens -> expert rows
+  y   = einsum('bse,besd->bsd', combine,  out)   # expert rows -> tokens
 
-Tokens beyond an expert's capacity (cap = S*k/E * capacity_factor) are
-dropped, GShard-style.  Returns (y, router load-balance aux loss).
+Why einsums: every op in both directions is a dot or elementwise, so
+GSPMD partitions forward AND backward cleanly (batch on 'data',
+expert/d_ff on 'model'); a sort + scatter form was measured at 40
+TB/device/step of involuntary all-reduce on mixtral-8x22b train_4k,
+because GSPMD cannot keep the batch dim sharded through batched
+scatters.  The expert axis is a batch dim of each expert matmul, so
+under the engine's client ``vmap`` (where the frozen experts are
+unbatched) one matmul per expert matrix reads each expert once per step
+for all clients.  (``jax.lax.ragged_dot`` has no vmap rule over an
+unbatched right-hand side in JAX 0.9.0.)
+
+The price is compute: every expert runs on every position of its row,
+E/k times the routed pairs (4x at E=8, k=2); a grouped matmul over the
+routed pairs, clients folded into the token axis, would remove it.
+
+Scopes: ``moe/route`` (router matmul, top-k, gates, dispatch and
+combine weights, the dispatch einsum) and ``moe/experts`` (the three
+expert matmuls and the combine), read by ``repro.obs.jitwatch``.
 """
 from __future__ import annotations
 
@@ -40,53 +56,44 @@ def init_moe(key, cfg: ModelConfig, dtype=jnp.bfloat16):
     }
 
 
-def _round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
+def max_load(counts: jnp.ndarray) -> jnp.ndarray:
+    """(L, E) routed pairs per layer and expert -> the largest expert's
+    share of its layer's pairs over the uniform share, maximised over
+    layers (1 when routing is even, E/k when every token of a layer
+    picks the same k experts)."""
+    e = counts.shape[-1]
+    share = counts.max(-1) / jnp.maximum(counts.sum(-1), 1.0)
+    return (share * e).max()
 
 
 def moe_ffn(p, cfg: ModelConfig, x: jnp.ndarray):
-    """x: (B, S, d) -> (y: (B, S, d), aux_loss: scalar f32)."""
+    """x: (B, S, d) -> (y (B, S, d), router load-balance aux loss,
+    routed pairs per expert (E,) f32)."""
     moe = cfg.moe
     e, k = moe.n_experts, moe.top_k
     b, s, d = x.shape
 
-    logits = (x.astype(jnp.float32) @ p["router"]["w"])           # (B, S, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, expert_ids = jax.lax.top_k(probs, k)                    # (B, S, k)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe/route"):
+        logits = x.astype(jnp.float32) @ p["router"]["w"]         # (B,S,E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, expert_ids = jax.lax.top_k(probs, k)                 # (B,S,k)
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+        onehot = jax.nn.one_hot(expert_ids, e, dtype=jnp.float32)  # (B,S,k,E)
+        counts = onehot.sum(axis=(0, 1, 2))
+        # load-balance aux (Switch): E * sum_e mean(route frac) * mean(prob)
+        aux = moe.router_aux_weight * e * jnp.sum(
+            counts / (b * s * k) * probs.mean(axis=(0, 1)))
+        # the k choices of a token are distinct experts: 0/1 entries
+        dispatch = onehot.sum(2).astype(x.dtype)                   # (B,S,E)
+        combine = (onehot * gate[..., None]).sum(2)                # (B,S,E)
+        buf = jnp.einsum("bse,bsd->besd", dispatch, x)
 
-    # load-balance aux (Switch): E * sum_e mean(route frac) * mean(prob)
-    onehot = jax.nn.one_hot(expert_ids, e, dtype=jnp.float32)     # (B,S,k,E)
-    frac = onehot.sum(axis=(0, 1, 2)) / (b * s * k)
-    aux = moe.router_aux_weight * e * jnp.sum(
-        frac * probs.mean(axis=(0, 1)))
-
-    cap = _round_up(max(k, int(s * k / e * moe.capacity_factor)), 8)
-
-    # position of each (token, choice) within its expert, priority (s, k).
-    # The big (T, E, cap) one-hots are kept in the activation dtype — at
-    # bf16 model scale this halves the dominant HBM traffic (§Perf #2 it3);
-    # dispatch entries are exactly 0/1 and gates carry ~8 mantissa bits,
-    # well inside PPO's noise floor.
-    mask = onehot.reshape(b, s * k, e)                            # (B,T,E)
-    pos = jnp.cumsum(mask, axis=1) - mask                         # (B,T,E)
-    within = mask * (pos < cap)                                   # keep/drop
-    pos_oh = jax.nn.one_hot(pos, cap, dtype=x.dtype)              # (B,T,E,cap)
-    dispatch = (within[..., None].astype(x.dtype) * pos_oh)       # (B,T,E,cap)
-    gate_flat = gate.reshape(b, s * k).astype(x.dtype)
-    combine = dispatch * gate_flat[:, :, None, None]              # weighted
-
-    # fold the k choices back onto tokens: (B, T=S*k, ...) -> (B,S,k,...)
-    disp_tok = dispatch.reshape(b, s, k, e, cap).sum(2)           # (B,S,E,cap)
-    comb_tok = combine.reshape(b, s, k, e, cap).sum(2)
-
-    buf = jnp.einsum("bsec,bsd->becd", disp_tok, x)
-
-    w = p["experts"]
-    g = jnp.einsum("becd,edf->becf", buf, w["w_gate"])
-    u = jnp.einsum("becd,edf->becf", buf, w["w_up"])
-    h = jax.nn.silu(g.astype(jnp.float32)).astype(buf.dtype) * u
-    out = jnp.einsum("becf,efd->becd", h, w["w_down"])            # (B,E,cap,d)
-
-    y = jnp.einsum("bsec,becd->bsd", comb_tok.astype(out.dtype), out)
-    return y.astype(x.dtype), aux
+    with jax.named_scope("moe/experts"):
+        w = p["experts"]
+        g = jnp.einsum("besd,edf->besf", buf, w["w_gate"])
+        u = jnp.einsum("besd,edf->besf", buf, w["w_up"])
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(buf.dtype) * u
+        out = jnp.einsum("besf,efd->besd", h, w["w_down"])         # (B,E,S,d)
+        y = jnp.einsum("bse,besd->bsd", combine.astype(out.dtype), out,
+                       preferred_element_type=jnp.float32)
+    return y.astype(x.dtype), aux, counts

@@ -73,7 +73,7 @@ def init_block(key, kind: str, cfg: ModelConfig, dtype=jnp.bfloat16):
     return p
 
 
-def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16):
+def _init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16):
     keys = jax.random.split(key, 6)
     params = {
         "embed": common._normal(keys[0], (cfg.vocab, cfg.d_model),
@@ -100,6 +100,22 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16):
             "final_norm": common.init_norm(cfg.d_model, dtype),
         }
     return params
+
+
+_init_params_jit = jax.jit(_init_params, static_argnames=("cfg", "dtype"))
+
+
+def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16):
+    """Every parameter drawn from ``key`` by one jitted program.
+
+    Each weight is drawn as float32 normals and rounded to ``dtype``;
+    inside one program XLA fuses the draw into the rounding, so neither
+    the float32 draw nor its random bits of a stacked weight are ever
+    held (eager, a stacked expert matrix of mixtral-8x7b would take 7.5
+    GB of float32 next to its 3.8 GB).  The values are those of the
+    eager ``_init_params`` up to the rounding of fused arithmetic.
+    """
+    return _init_params_jit(cfg, key, dtype)
 
 
 # ================================================================ seq mode
@@ -131,15 +147,17 @@ def _cross_attention(p, cfg: ModelConfig, h, cross_states):
 
 def block_seq(kind: str, p, cfg: ModelConfig, x, positions, cross_states,
               collect_kv: bool):
-    """Apply one block in sequence mode.  Returns (x, aux_loss, kv_piece)."""
-    aux = jnp.zeros((), jnp.float32)
+    """Apply one block in sequence mode.  Returns (x, moe, kv_piece):
+    ``moe`` is (router aux loss, routed pairs per expert) for an expert
+    block, else None."""
+    moe = None
     stateful = {"mamba2": ssm.mamba2_seq, "mlstm": xlstm.mlstm_seq,
                 "slstm": xlstm.slstm_seq}
     if kind in stateful:
         if collect_kv:
             x2, st = stateful[kind](p, cfg, x, return_state=True)
-            return x2, aux, st
-        return stateful[kind](p, cfg, x), aux, None
+            return x2, moe, st
+        return stateful[kind](p, cfg, x), moe, None
     h = common.rms_norm(p["ln1"], x, cfg.norm_eps)
     attn_out, kv = _self_attention(p["attn"], cfg, h, positions, kind)
     x = x + attn_out
@@ -150,7 +168,8 @@ def block_seq(kind: str, p, cfg: ModelConfig, x, positions, cross_states,
         x = x + cross_out
     h2 = common.rms_norm(p["ln2"], x, cfg.norm_eps)
     if kind in ("moe", "moe_swa"):
-        y, aux = moe_lib.moe_ffn(p["moe"], cfg, h2)
+        y, aux, counts = moe_lib.moe_ffn(p["moe"], cfg, h2)
+        moe = (aux, counts)
     else:
         y = common.swiglu(p["mlp"], h2)
     x = x + y
@@ -159,7 +178,7 @@ def block_seq(kind: str, p, cfg: ModelConfig, x, positions, cross_states,
         piece = {"k": kv[0], "v": kv[1]}
         if ckv is not None:
             piece["ck"], piece["cv"] = ckv
-    return x, aux, piece
+    return x, moe, piece
 
 
 def _encoder_forward(cfg: ModelConfig, params, frames):
@@ -186,10 +205,13 @@ def _cross_source(cfg: ModelConfig, params, aux):
 
 def forward_seq(cfg: ModelConfig, params, tokens, aux=None,
                 collect_kv: bool = False, last_logit_only: bool = False):
-    """tokens: (B, S) int32 -> dict(logits, hidden, aux_loss [, kv]).
+    """tokens: (B, S) int32 -> dict(logits, hidden, aux_loss [, kv]
+    [, moe_counts]).
 
     last_logit_only: compute logits for the final position only (prefill
     path — avoids materialising (B, S, V) at 32k x 200k scale).
+    ``moe_counts`` (a model with expert blocks): (L_moe, E) routed pairs
+    per expert block, in layer order, and expert.
     """
     x = jnp.take(params["embed"], tokens, axis=0)
     positions = jnp.arange(tokens.shape[1])
@@ -198,15 +220,18 @@ def forward_seq(cfg: ModelConfig, params, tokens, aux=None,
 
     def period_body(carry, slot_params):
         x, aux_sum = carry
-        pieces = {}
+        pieces, counts = {}, []
         for i, kind in enumerate(cfg.pattern):
             p = shared if kind == "shared_attn" else slot_params[str(i)]
-            x, a, piece = block_seq(kind, p, cfg, x, positions, cross_states,
-                                    collect_kv)
-            aux_sum = aux_sum + a
+            x, moe, piece = block_seq(kind, p, cfg, x, positions,
+                                      cross_states, collect_kv)
+            if moe is not None:
+                aux_sum = aux_sum + moe[0]
+                counts.append(moe[1])
             if collect_kv:
                 pieces[str(i)] = piece
-        return (x, aux_sum), pieces if collect_kv else None
+        return (x, aux_sum), (pieces if collect_kv else None,
+                              jnp.stack(counts) if counts else None)
 
     xs = {i: v for i, v in params["slots"].items()}
     body = period_body
@@ -219,12 +244,14 @@ def forward_seq(cfg: ModelConfig, params, tokens, aux=None,
         policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                   if cfg.remat_policy == "dots" else None)
         body = jax.checkpoint(period_body, policy=policy)
-    (x, aux_loss), kv = jax.lax.scan(
+    (x, aux_loss), (kv, counts) = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.float32)), xs)
     x = common.rms_norm(params["final_norm"], x, cfg.norm_eps)
     logits = common.linear(params["lm_head"],
                            x[:, -1:] if last_logit_only else x)
     out = {"logits": logits, "hidden": x, "aux_loss": aux_loss}
+    if counts is not None:
+        out["moe_counts"] = counts.reshape(-1, counts.shape[-1])
     if collect_kv:
         out["kv"] = kv
         out["cross_states"] = cross_states
@@ -314,7 +341,7 @@ def block_decode(kind: str, p, cfg: ModelConfig, x, cache, pos):
         x = x + common.linear(p["cross"]["wo"], o.reshape(b, 1, hq * dh))
     h2 = common.rms_norm(p["ln2"], x, cfg.norm_eps)
     if kind in ("moe", "moe_swa"):
-        y, _ = moe_lib.moe_ffn(p["moe"], cfg, h2)
+        y, _, _ = moe_lib.moe_ffn(p["moe"], cfg, h2)
     else:
         y = common.swiglu(p["mlp"], h2)
     return x + y, new_cache

@@ -41,12 +41,14 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 
 # The round's layers: one jax.named_scope each, placed around the code
-# that does the work (sampling, engine, codec).
+# that does the work (sampling, engine, codec); the model's expert layer
+# (``models/moe.py``) nests inside the phases that run the model.
 LAYERS = (
     "participants", "keys", "downlink_codec", "sample_prompts",
     "generate/prefill", "generate/decode", "rewards", "ref_forward",
     "local_step/grads", "local_step/mgda", "local_step/adam",
     "local_step/critic_kl", "delta", "uplink_codec", "aggregate", "summary",
+    "moe/route", "moe/experts",
 )
 
 
@@ -201,6 +203,13 @@ def scope_of(op_name: str) -> Optional[str]:
     return found[-1] if found else None
 
 
+def phase_of(op_name: str) -> Optional[str]:
+    """The outermost layer of ``LAYERS`` in an op's name stack: the
+    round's phase an op of a nested layer (``moe/experts``) runs in."""
+    found = _SCOPE.findall(op_name)
+    return found[0] if found else None
+
+
 def _operands(rest: str, start: int) -> List[str]:
     """The instruction names between the opcode's parentheses."""
     depth, i = 1, start
@@ -210,19 +219,20 @@ def _operands(rest: str, start: int) -> List[str]:
     return _OPERAND.findall(rest[start:i])
 
 
-def hlo_ops(text: str) -> Tuple[str, List[Tuple[str, str, Optional[str]]]]:
-    """(module name, [(instruction, opcode, layer)]) of a compiled
+def hlo_ops(text: str) -> Tuple[
+        str, List[Tuple[str, str, Optional[str], Optional[str]]]]:
+    """(module name, [(instruction, opcode, layer, phase)]) of a compiled
     module's text: every instruction of the computations that run as op
     sequences (the entry, while bodies and conditions, conditional
     branches, called computations) that does work; fused computations
     are inside their fusion's op.
 
     An instruction takes the innermost layer of ``LAYERS`` in its name
-    stack.  One outside every named scope (the layout copies,
-    prefetches and loop-carried copies XLA adds have no name) takes the
-    layer of its users where they all have one and the same, else that
-    of the while, conditional or call that holds it (None in the
-    entry)."""
+    stack, and the outermost as its phase.  One outside every named
+    scope (the layout copies, prefetches and loop-carried copies XLA
+    adds have no name) takes the (layer, phase) of its users where they
+    all have one and the same, else that of the while, conditional or
+    call that holds it (None in the entry)."""
     module = re.match(r"HloModule\s+([^\s,]+)", text).group(1)
     comps: Dict[str, list] = {}
     entry, cur = None, None
@@ -247,8 +257,10 @@ def hlo_ops(text: str) -> Tuple[str, List[Tuple[str, str, Optional[str]]]]:
         if op.group(1) == "call":
             called += _TO_APPLY.findall(rest)
         name_stack = _OP_NAME.search(rest)
+        stack = name_stack.group(1) if name_stack else ""
         cur.append((m.group(1), op.group(1),
-                    scope_of(name_stack.group(1)) if name_stack else None,
+                    (scope_of(stack), phase_of(stack)) if scope_of(stack)
+                    else None,
                     called, _operands(rest, op.end())))
     out, todo, seen = [], [(entry, None)], set()
     while todo:
@@ -260,7 +272,7 @@ def hlo_ops(text: str) -> Tuple[str, List[Tuple[str, str, Optional[str]]]]:
         for name, _, _, _, operands in comps[comp]:
             for o in operands:
                 users.setdefault(o, []).append(name)
-        own: Dict[str, Optional[str]] = {}
+        own: Dict[str, Optional[Tuple[str, str]]] = {}
         for name, _, scope, _, _ in reversed(comps[comp]):
             theirs = {own.get(u) for u in users.get(name, ())}
             if scope is None and len(theirs) == 1:
@@ -269,7 +281,7 @@ def hlo_ops(text: str) -> Tuple[str, List[Tuple[str, str, Optional[str]]]]:
         for name, opcode, _, called, _ in comps[comp]:
             todo.extend((c, own[name]) for c in called)
             if opcode not in _NO_OP:
-                out.append((name, opcode, own[name]))
+                out.append((name, opcode) + (own[name] or (None, None)))
     return module, out
 
 
@@ -278,14 +290,19 @@ class ProgramMap:
     name: str                          # the jitwatch name
     layer: Optional[str]               # the whole program's layer, if one
     ops: Dict[str, Optional[str]]      # leaf instruction -> layer
+    # leaf instruction -> phase (the outermost layer: ``generate/decode``
+    # for an expert matmul of the decode scan)
+    phases: Dict[str, Optional[str]] = dataclasses.field(
+        default_factory=dict)
 
 
 def layer_map(names=None) -> Dict[str, ProgramMap]:
     """{compiled module name: ProgramMap} of every wrapped program that
     has been called (or of those among ``names``), leaves only (no
-    while, conditional or call).  A program whose named ops all lie in
-    one layer is that layer as a whole (the codec's, the aggregation's):
-    its unnamed ops count there too.  Call it after the traced window:
+    while, conditional or call), each with its layer and its phase.  A
+    program whose named ops all lie in one layer is that layer as a
+    whole (the codec's, the aggregation's): its unnamed ops count there
+    too.  Call it after the traced window:
     it lowers and compiles each program from its kept signature, which
     JAX's compile cache answers."""
     out = {}
@@ -295,10 +312,12 @@ def layer_map(names=None) -> Dict[str, ProgramMap]:
         args, kwargs = prog.sig
         text = prog.jitted.lower(*args, **kwargs).compile().as_text()
         module, ops = hlo_ops(text)
-        leaves = {name: lay for name, opcode, lay in ops
+        leaves = {name: (lay, phase) for name, opcode, lay, phase in ops
                   if opcode not in _HOLDERS}
-        named = set(leaves.values()) - {None}
+        named = {lay for lay, _ in leaves.values()} - {None}
         whole = named.pop() if len(named) == 1 else None
-        out[module] = ProgramMap(prog.name, whole, {
-            name: lay or whole for name, lay in leaves.items()})
+        out[module] = ProgramMap(
+            prog.name, whole,
+            {name: lay or whole for name, (lay, _) in leaves.items()},
+            {name: phase or whole for name, (_, phase) in leaves.items()})
     return out

@@ -95,7 +95,8 @@ def round_summary(*, stats: Dict[str, Any], comm_bytes: int, up_bytes: int,
 
     ``stats`` holds the device-computed statistics after the round's one
     host transfer (keys: rewards, lam_mean, lam_disagreement,
-    param_drift, kl, per_client_lam, rewards_per_client).  Both the
+    param_drift, kl, per_client_lam, rewards_per_client, and for a model
+    with expert blocks moe_max_load).  Both the
     per-round and the fused executors call this with their own slices;
     ``tests/test_obs.py`` pins the output bit-identical to the legacy
     hand-built dicts.
@@ -118,6 +119,8 @@ def round_summary(*, stats: Dict[str, Any], comm_bytes: int, up_bytes: int,
         "local_steps": list(local_steps),
         "cohorts": cohorts,
     }
+    if "moe_max_load" in stats:
+        summary["moe_max_load"] = float(stats["moe_max_load"])
     if fused is not None:
         summary["fused"] = fused
     return summary
@@ -190,6 +193,7 @@ def records_from_round(summary: dict, *, round: Optional[int] = None,
     g("round/kl", "kl")
     g("round/dispatches", "dispatches")
     g("round/cohorts", "cohorts")
+    g("round/moe_max_load", "moe_max_load")
     s("round/local_steps", "local_steps")
     c("comm/total_bytes", "comm_bytes")
     c("comm/up_bytes", "up_bytes")

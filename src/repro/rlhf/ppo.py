@@ -83,6 +83,14 @@ def masked_mean(x, mask):
     return (x * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
 
+def router_trains(trainable) -> bool:
+    """Does any router parameter of an expert layer train?  (Not under
+    LoRA, whose adapters sit on attention only.)"""
+    flat = jax.tree_util.tree_flatten_with_path(trainable)[0]
+    return any(any(getattr(k, "key", None) == "router" for k in path)
+               for path, _ in flat)
+
+
 def multi_objective_losses(cfg: ModelConfig, fc: FIRMConfig, trainable,
                            frozen, critic, batch: PPOBatch, kl_coef,
                            aux: Optional[dict] = None):
@@ -111,7 +119,10 @@ def multi_objective_losses(cfg: ModelConfig, fc: FIRMConfig, trainable,
     clipped = jnp.clip(ratio, 1.0 - fc.ppo_clip, 1.0 + fc.ppo_clip)
     pg = -jnp.minimum(ratio[..., None] * adv, clipped[..., None] * adv)
     losses = (pg * mask[..., None]).sum((0, 1)) / jnp.maximum(mask.sum(), 1.0)
-    losses = losses + out["aux_loss"]                        # MoE router aux
+    # the router's load-balance loss is a pretraining regulariser: it
+    # enters the objectives only where the router itself trains
+    if router_trains(trainable):
+        losses = losses + out["aux_loss"]
 
     metrics = {
         "kl": masked_mean(kl, mask),
@@ -119,6 +130,8 @@ def multi_objective_losses(cfg: ModelConfig, fc: FIRMConfig, trainable,
         "entropy_proxy": -masked_mean(lp, mask),
         "aux_loss": out["aux_loss"],
     }
+    if "moe_counts" in out:
+        metrics["moe_counts"] = out["moe_counts"]
     return losses, (metrics, feats, r_tok, rets, mask)
 
 

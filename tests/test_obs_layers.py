@@ -122,9 +122,11 @@ def test_round_program_leaves_map_to_one_layer_each(round_map):
         found = set(jitwatch._SCOPE.findall(op_name))
         assert len(found) == 1 and found <= LOCAL_LAYERS, op_name
     # the decode scan is one while, under generate/decode
-    whiles = {lay for _, opc, lay in ops if opc == "while"}
+    whiles = {lay for _, opc, lay, _ in ops if opc == "while"}
     assert "generate/decode" in whiles
-    assert not any(opc == "while" for op, opc, _ in ops if op in pm.ops)
+    assert not any(opc == "while" for op, opc, _, _ in ops if op in pm.ops)
+    # a dense model nests no layer: every op's phase is its layer
+    assert pm.phases == pm.ops
 
 
 def test_whole_program_layers(round_map):
@@ -153,6 +155,34 @@ def test_scope_of_reads_the_innermost_layer_and_skips_jit_names():
     assert jitwatch.scope_of("jit(summary_device)/add") is None
     assert jitwatch.scope_of("jit(f)/jit(rewards)/add") is None
     assert jitwatch.scope_of("jit(f)/rewards/jit(_where)/select") == "rewards"
+
+
+def test_an_expert_layer_nests_inside_the_phase_that_runs_it():
+    decode = ("jit(vec_round[firm])/while/body/vmap(generate/decode)/while/"
+              "body/moe/experts/dot_general")
+    assert jitwatch.scope_of(decode) == "moe/experts"
+    assert jitwatch.phase_of(decode) == "generate/decode"
+    grads = ("jit(f)/vmap(local_step/grads)/transpose(jvp(moe/route))/"
+             "dot_general")
+    assert jitwatch.scope_of(grads) == "moe/route"
+    assert jitwatch.phase_of(grads) == "local_step/grads"
+    assert jitwatch.phase_of("jit(f)/rewards/add") == "rewards"
+    assert jitwatch.phase_of("jit(summary_device)/add") is None
+    text = """HloModule jit_toy, entry_computation_layout={()->f32[]}
+
+ENTRY %main (x: f32[]) -> f32[] {
+  %x = f32[] parameter(0)
+  %dot.1 = f32[] multiply(%x, %x), metadata={op_name="jit(toy)/ref_forward/moe/experts/dot_general"}
+  %copy.2 = f32[] copy(%dot.1)
+  ROOT %add.3 = f32[] add(%copy.2, %x), metadata={op_name="jit(toy)/ref_forward/moe/experts/add"}
+}
+"""
+    _, ops = jitwatch.hlo_ops(text)
+    assert sorted(ops) == [
+        ("add.3", "add", "moe/experts", "ref_forward"),
+        # an unnamed op takes its user's layer and phase
+        ("copy.2", "copy", "moe/experts", "ref_forward"),
+        ("dot.1", "multiply", "moe/experts", "ref_forward")]
 
 
 def test_hlo_ops_skips_fused_computations_and_resolves_unnamed_ops():
@@ -186,17 +216,17 @@ ENTRY %main (x: f32[]) -> f32[] {
 """
     module, ops = jitwatch.hlo_ops(text)
     assert module == "jit_toy"
-    assert sorted(ops) == sorted([
-        ("fusion.1", "fusion", "rewards"),
+    assert sorted(ops, key=str) == sorted([
+        ("fusion.1", "fusion", "rewards", "rewards"),
         # an unnamed copy takes its one user's layer ...
-        ("copy.5", "copy", "generate/decode"),
+        ("copy.5", "copy", "generate/decode", "generate/decode"),
         # ... and with users in different layers, none
-        ("copy.6", "copy", None),
-        ("add.7", "add", "aggregate"),
-        ("while.2", "while", "generate/decode"),
+        ("copy.6", "copy", None, None),
+        ("add.7", "add", "aggregate", "aggregate"),
+        ("while.2", "while", "generate/decode", "generate/decode"),
         # inside the loop, the loop's
-        ("copy.3", "copy", "generate/decode"),
-        ("lt", "compare", "generate/decode")])
+        ("copy.3", "copy", "generate/decode", "generate/decode"),
+        ("lt", "compare", "generate/decode", "generate/decode")], key=str)
 
 
 def test_an_inactive_wrapper_keeps_only_the_first_signature(monkeypatch):
