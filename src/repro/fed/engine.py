@@ -192,14 +192,20 @@ def _make_round_fn(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
     return round_fn
 
 
-def _moe_max_load(ms):
-    """The round's ``moe_max_load`` from the local phase's stacked
-    metrics: routed pairs of the policy forward summed over the K steps
-    and the clients, per layer and expert (``moe.max_load``); None for a
-    model without expert blocks."""
+def _moe_stats(cfg: ModelConfig, ms, tokens: int):
+    """The round's expert-layer statistics from the local phase's stacked
+    metrics, the routed pairs of the policy forward per step, client,
+    layer and expert: ``moe_max_load`` (``moe.max_load`` of the pairs
+    summed over the K steps and the clients) and ``moe_rows_per_pair``
+    (``moe.rows_per_pair`` of each step's forward, which folds every
+    client's ``tokens`` tokens); {} for a model without expert blocks."""
     if "moe_counts" not in ms:
-        return None
-    return moe_lib.max_load(ms["moe_counts"].sum((0, 1)))
+        return {}
+    counts = ms["moe_counts"]                               # (K, C, L, E)
+    k = cfg.moe.top_k
+    return {"moe_max_load": moe_lib.max_load(counts.sum((0, 1))),
+            "moe_rows_per_pair": moe_lib.rows_per_pair(
+                counts.sum(1), counts.shape[1] * tokens * k, k)}
 
 
 def _split_next(rng):
@@ -242,6 +248,7 @@ def _jit_vec_round(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
     round_fn = _make_round_fn(cfg, cfc, kernel, prompt_len, max_new,
                               length_tol, has_pref)
     k_steps = cfc.local_steps
+    tokens = cfc.batch_size * (prompt_len + max_new)     # a client's
 
     def vec_round(state, frozen, ref_trainable, seeds, counts, probs,
                   band_h, band_x, keys, pref, extra, idx=None):
@@ -266,7 +273,7 @@ def _jit_vec_round(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
                      ms["rewards"].mean(0).mean(0),
                      ms["kl"].mean(0).mean(0),
                      ms["rewards"].mean(0),                    # (P, M)
-                     _moe_max_load(ms))
+                     _moe_stats(cfg, ms, tokens))
         counts = (counts + k_steps if idx is None
                   else counts.at[idx].add(k_steps))
         return final, stats, up_keys, rng, counts
@@ -360,7 +367,7 @@ def _f32(x: float):
 
 
 def _summary_device_fn(lams, rewards_mean, kl_mean, stacked_trainable,
-                       rewards_pc, moe_max_load=None):
+                       rewards_pc, moe=None):
     """All round-summary statistics computed device-side; the engine does
     ONE host transfer per round (jax.device_get of this dict)."""
     with jax.named_scope("summary"):
@@ -374,8 +381,7 @@ def _summary_device_fn(lams, rewards_mean, kl_mean, stacked_trainable,
             "per_client_lam": lams,
             "rewards_per_client": rewards_pc,
         }
-        if moe_max_load is not None:
-            out["moe_max_load"] = moe_max_load
+        out.update(moe or {})
         return out
 
 
@@ -392,9 +398,9 @@ class LocalPhaseResult(NamedTuple):
     # (P, 2) uplink keys, when the phase drew them in-graph after the
     # generation keys; None when the caller draws them with _next_key
     up_keys: Optional[jnp.ndarray] = None
-    # the policy forward's expert load (``_moe_max_load``), where the
-    # phase's program measured it
-    moe_max_load: Optional[jnp.ndarray] = None
+    # the policy forward's expert-layer statistics (``_moe_stats``),
+    # where the phase's program measured them
+    moe: Optional[dict] = None
 
 
 class FusedCarry(NamedTuple):
@@ -449,6 +455,7 @@ def _jit_fused_rounds(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
     ul = make_codec(uplink_spec)
     dl = make_codec(downlink_spec)
     k_steps = cfc.local_steps
+    tokens = cfc.batch_size * (prompt_len + max_new)     # a client's
     full = n_part >= n_clients
 
     def fused(carry, global_tr, round_idxs, part_base, frozen, ref_trainable,
@@ -550,8 +557,7 @@ def _jit_fused_rounds(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
                     "rewards_per_client": ms["rewards"].mean(0),
                     "participants": idx,
                 }
-                if "moe_counts" in ms:
-                    ys["moe_max_load"] = _moe_max_load(ms)
+                ys.update(_moe_stats(cfg, ms, tokens))
             return (states, g_tree, ul_state, dl_state, counts, rng), ys
 
         init = (carry.states, global_tr, carry.ul_state, carry.dl_state,
@@ -826,7 +832,7 @@ class FederatedTrainer:
         with jitwatch.span("round/summary"):
             stats = _summary_device(res.lams, res.rewards_mean,
                                     res.kl_mean, res.stacked_trainable,
-                                    res.rewards_pc, res.moe_max_load)
+                                    res.rewards_pc, res.moe)
             self.jit_dispatches += 1
             host = jax.device_get(stats)
         self.host_transfers += 1
@@ -953,7 +959,8 @@ class FederatedTrainer:
                 stats={k: ys_h[k][r] for k in
                        ("rewards", "lam_mean", "lam_disagreement",
                         "param_drift", "kl", "per_client_lam",
-                        "rewards_per_client", "moe_max_load")
+                        "rewards_per_client", "moe_max_load",
+                        "moe_rows_per_pair")
                        if k in ys_h},
                 comm_bytes=self.ledger.total,
                 up_bytes=self.ledger.up_bytes,
@@ -1034,7 +1041,7 @@ class FederatedTrainer:
         stacked = _stack_trees_jit(*states)
         self.jit_dispatches += 1
 
-        up_keys = moe_load = None
+        up_keys = moe = None
         if not self.algorithm.caps.traced_server_exchange:
             # host-driven server exchange: the algorithm owns the phase
             # (jitted client phases around its host exchange)
@@ -1065,7 +1072,7 @@ class FederatedTrainer:
                 self._stacked_pref, extra,
                 None if full else np.asarray(participants, np.int32))
             self.jit_dispatches += 1
-            lams, rewards_mean, kl_mean, rewards_pc, moe_load = stats
+            lams, rewards_mean, kl_mean, rewards_pc, moe = stats
             if rng is not None:
                 self._rng = rng
             self._counts = (tuple(ds._count for ds in self.datasets),
@@ -1077,7 +1084,7 @@ class FederatedTrainer:
             self.client_states[c] = new_states[ci]
         return LocalPhaseResult(lams, rewards_mean, kl_mean,
                                 stacked.trainable, rewards_pc, up_keys,
-                                moe_load)
+                                moe)
 
     # ------------------------------------------------- cohort dispatch
     def _local_phase_cohorts(self, plan, participants: List[int],
@@ -1103,7 +1110,7 @@ class FederatedTrainer:
         pos = {c: i for i, c in enumerate(participants)}
         lam_rows = [None] * len(participants)
         rpc_rows = [None] * len(participants)
-        stacked_parts, order, loads = [], [], []
+        stacked_parts, order, moes = [], [], []
         rew_acc, kl_acc, w_tot = 0.0, 0.0, 0
         for co in plan:
             members = list(co.members)
@@ -1121,19 +1128,19 @@ class FederatedTrainer:
             w_tot += w
             stacked_parts.append(res.stacked_trainable)
             order.extend(members)
-            if res.moe_max_load is not None:
-                loads.append(res.moe_max_load)
+            if res.moe:
+                moes.append(res.moe)
 
         inv = jnp.asarray([order.index(c) for c in participants], jnp.int32)
         stacked_tr = jax.tree_util.tree_map(
             lambda *xs: jnp.concatenate(xs, axis=0)[inv], *stacked_parts)
         self.jit_dispatches += 1
-        # the most uneven cohort's load
-        return LocalPhaseResult(jnp.stack(lam_rows), rew_acc / w_tot,
-                                kl_acc / w_tot, stacked_tr,
-                                jnp.stack(rpc_rows),
-                                moe_max_load=(jnp.max(jnp.stack(loads))
-                                              if loads else None))
+        # the most uneven cohort's load, the most padded cohort's rows
+        return LocalPhaseResult(
+            jnp.stack(lam_rows), rew_acc / w_tot, kl_acc / w_tot,
+            stacked_tr, jnp.stack(rpc_rows),
+            moe=({k: jnp.max(jnp.stack([m[k] for m in moes]))
+                  for k in moes[0]} if moes else None))
 
     def run(self, rounds: Optional[int] = None) -> List[dict]:
         total = rounds or self.fc.rounds

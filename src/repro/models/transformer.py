@@ -195,6 +195,30 @@ def _encoder_forward(cfg: ModelConfig, params, frames):
     return common.rms_norm(enc["final_norm"], x, cfg.norm_eps)
 
 
+def _split_experts(slots):
+    """The per-period scan inputs without the expert matrices, and those
+    matrices stacked over periods, per slot.  An expert layer reads its
+    period's experts from the stack (``_join_experts``): a grouped
+    matmul is a kernel call, and an operand the scan sliced out of the
+    stack would first be copied whole (2.7 GB a Mixtral-8x7B layer)."""
+    xs, stacked = {}, {}
+    for i, p in slots.items():
+        if "moe" in p:
+            stacked[i] = p["moe"]["experts"]
+            p = dict(p, moe={k: v for k, v in p["moe"].items()
+                             if k != "experts"})
+        xs[i] = p
+    return xs, stacked
+
+
+def _join_experts(p, stacked, i: str, period):
+    """Slot ``i``'s block parameters for ``period``: the expert layer's
+    stacked matrices and the period's index into them."""
+    if i not in stacked:
+        return p
+    return dict(p, moe=dict(p["moe"], experts=stacked[i], layer=period))
+
+
 def _cross_source(cfg: ModelConfig, params, aux):
     if cfg.family == "vlm":
         return aux["vision"].astype(params["embed"].dtype)
@@ -218,11 +242,15 @@ def forward_seq(cfg: ModelConfig, params, tokens, aux=None,
     cross_states = _cross_source(cfg, params, aux or {})
     shared = params.get("shared")
 
-    def period_body(carry, slot_params):
+    xs, experts = _split_experts(params["slots"])
+
+    def period_body(carry, period_xs):
         x, aux_sum = carry
+        slot_params, period = period_xs
         pieces, counts = {}, []
         for i, kind in enumerate(cfg.pattern):
-            p = shared if kind == "shared_attn" else slot_params[str(i)]
+            p = shared if kind == "shared_attn" else _join_experts(
+                slot_params[str(i)], experts, str(i), period)
             x, moe, piece = block_seq(kind, p, cfg, x, positions,
                                       cross_states, collect_kv)
             if moe is not None:
@@ -233,7 +261,6 @@ def forward_seq(cfg: ModelConfig, params, tokens, aux=None,
         return (x, aux_sum), (pieces if collect_kv else None,
                               jnp.stack(counts) if counts else None)
 
-    xs = {i: v for i, v in params["slots"].items()}
     body = period_body
     if cfg.remat and not collect_kv:
         # activation checkpointing: store only the period-boundary x;
@@ -245,7 +272,8 @@ def forward_seq(cfg: ModelConfig, params, tokens, aux=None,
                   if cfg.remat_policy == "dots" else None)
         body = jax.checkpoint(period_body, policy=policy)
     (x, aux_loss), (kv, counts) = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), xs)
+        body, (x, jnp.zeros((), jnp.float32)),
+        (xs, jnp.arange(cfg.n_periods)))
     x = common.rms_norm(params["final_norm"], x, cfg.norm_eps)
     logits = common.linear(params["lm_head"],
                            x[:, -1:] if last_logit_only else x)
@@ -353,16 +381,20 @@ def decode_step(cfg: ModelConfig, params, cache, token):
     pos = cache["pos"]
     shared = params.get("shared")
 
+    slots, experts = _split_experts(params["slots"])
+
     def period_body(x, xs):
-        slot_params, slot_caches = xs
+        slot_params, slot_caches, period = xs
         new_caches = {}
         for i, kind in enumerate(cfg.pattern):
             p = shared if kind == "shared_attn" else slot_params.get(str(i))
+            if p is not None:
+                p = _join_experts(p, experts, str(i), period)
             x, new_caches[str(i)] = block_decode(kind, p, cfg, x,
                                                  slot_caches[str(i)], pos)
         return x, new_caches
 
-    xs = (params["slots"], cache["slots"])
+    xs = (slots, cache["slots"], jnp.arange(cfg.n_periods))
     x, new_slots = jax.lax.scan(period_body, x, xs)
     x = common.rms_norm(params["final_norm"], x, cfg.norm_eps)
     logits = common.linear(params["lm_head"], x)[:, 0]

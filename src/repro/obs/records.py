@@ -96,7 +96,7 @@ def round_summary(*, stats: Dict[str, Any], comm_bytes: int, up_bytes: int,
     ``stats`` holds the device-computed statistics after the round's one
     host transfer (keys: rewards, lam_mean, lam_disagreement,
     param_drift, kl, per_client_lam, rewards_per_client, and for a model
-    with expert blocks moe_max_load).  Both the
+    with expert blocks moe_max_load and moe_rows_per_pair).  Both the
     per-round and the fused executors call this with their own slices;
     ``tests/test_obs.py`` pins the output bit-identical to the legacy
     hand-built dicts.
@@ -119,8 +119,9 @@ def round_summary(*, stats: Dict[str, Any], comm_bytes: int, up_bytes: int,
         "local_steps": list(local_steps),
         "cohorts": cohorts,
     }
-    if "moe_max_load" in stats:
-        summary["moe_max_load"] = float(stats["moe_max_load"])
+    for key in ("moe_max_load", "moe_rows_per_pair"):
+        if key in stats:
+            summary[key] = float(stats[key])
     if fused is not None:
         summary["fused"] = fused
     return summary
@@ -194,6 +195,7 @@ def records_from_round(summary: dict, *, round: Optional[int] = None,
     g("round/dispatches", "dispatches")
     g("round/cohorts", "cohorts")
     g("round/moe_max_load", "moe_max_load")
+    g("round/moe_rows_per_pair", "moe_rows_per_pair")
     s("round/local_steps", "local_steps")
     c("comm/total_bytes", "comm_bytes")
     c("comm/up_bytes", "up_bytes")

@@ -2,13 +2,15 @@
 float32 reference (``models/moe_reference.py``), at a small size on
 seeded random weights: forward, prefill + decode through the cache,
 per-objective LoRA gradients, skewed routing, the jitted init, the
-router loss kept out of FIRM's objectives, and the round's load
-counter."""
+router loss kept out of FIRM's objectives, the round's load counters,
+and the grouped layout against the einsum layout, batched and not."""
 import dataclasses
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.configs.base import FIRMConfig, MoEConfig
@@ -216,3 +218,195 @@ def test_the_float8_control_rounds_as_a_float8_cast():
     np.testing.assert_array_equal(
         np.asarray(jax.grad(loss)(x, R.round_e4m3)),
         np.asarray(jax.grad(loss)(x, cast)))
+
+
+# ------------------------------------------------- grouped vs einsum layout
+def _layer_params(cfg, key=KEY):
+    """One expert layer's float32 parameters and the same stacked as
+    the reference reads them (one layer)."""
+    p = moe_lib.init_moe(key, cfg, dtype=jnp.float32)
+    return p, jax.tree_util.tree_map(lambda a: a[None], p)
+
+
+def _force(monkeypatch, form, tile=8):
+    """Steer the layout choice at a small size: the grouped layout on
+    any shape (with a small row tile), or the einsum layout on any."""
+    if form == "grouped":
+        monkeypatch.setattr(moe_lib, "GROUPED_MIN_ROWS", 1)
+        monkeypatch.setattr(moe_lib, "_row_tile", lambda n, e: tile)
+    else:
+        monkeypatch.setattr(moe_lib, "GROUPED_MIN_ROWS", 10 ** 9)
+
+
+# tokens; a zero router ties every expert, so each token takes experts 0
+# and 1; three tokens route 6 pairs, leaving at least two experts empty
+# in the grouped layout; 37 tokens route 74 pairs, not a multiple of the
+# row tile
+LAYOUT_CASES = {"ragged_rows": (37, False), "empty_expert": (3, False),
+                "same_two_experts": (24, True)}
+
+
+@pytest.mark.parametrize("case", sorted(LAYOUT_CASES))
+def test_grouped_layout_matches_einsum_layout_and_reference(case,
+                                                            monkeypatch):
+    """Forward output and input gradient (through the experts and the
+    router's gates) of the grouped layout equal the einsum layout's and
+    the float32 reference's, routed as the program routed."""
+    cfg = _cfg()
+    t, zero_router = LAYOUT_CASES[case]
+    p, stacked = _layer_params(cfg)
+    if zero_router:
+        p["router"]["w"] = jnp.zeros_like(p["router"]["w"])
+        stacked["router"]["w"] = jnp.zeros_like(stacked["router"]["w"])
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, t, cfg.d_model))
+    r = jax.random.normal(jax.random.PRNGKey(4), x.shape)
+
+    def program(form):
+        _force(monkeypatch, form)
+
+        def loss(x_):
+            y, _, counts = moe_lib.moe_ffn(p, cfg, x_)
+            return jnp.sum(y * r), (y, counts)
+        (_, (y, counts)), dx = jax.jit(
+            jax.value_and_grad(loss, has_aux=True))(x)
+        return y, dx, np.asarray(counts)
+
+    y_g, dx_g, counts = program("grouped")
+    y_e, dx_e, counts_e = program("einsum")
+    np.testing.assert_array_equal(counts, counts_e)
+    if case == "empty_expert":
+        assert (counts == 0).sum() >= 2
+    if case == "same_two_experts":
+        assert (counts[:2] == t).all() and (counts[2:] == 0).all()
+    assert _rel(y_g, y_e) < 1e-5 and _rel(dx_g, dx_e) < 1e-5
+
+    probs = jax.nn.softmax(x[0] @ p["router"]["w"], -1)
+    ids = jax.lax.top_k(probs, cfg.moe.top_k)[1]
+
+    def ref_loss(x_):
+        return jnp.sum(R.moe_layer(stacked, cfg, x_, 0, ids=ids)[0] * r)
+    with jax.default_matmul_precision("highest"):
+        y_r = R.moe_layer(stacked, cfg, x, 0, ids=ids)[0]
+        dx_r = jax.grad(ref_loss)(x)
+    assert _rel(y_g, y_r) < 1e-4 and _rel(dx_g, dx_r) < 1e-4
+
+
+def _pallas_calls(closed):
+    """(operand shapes, output shapes) of every Pallas call in a jaxpr,
+    sub-jaxprs included."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(([tuple(v.aval.shape) for v in eqn.invars],
+                              [tuple(v.aval.shape) for v in eqn.outvars]))
+            for param in eqn.params.values():
+                for sub in (param if isinstance(param, (list, tuple))
+                            else [param]):
+                    if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                        walk(sub.jaxpr)
+                    elif isinstance(sub, jax.extend.core.Jaxpr):
+                        walk(sub)
+    walk(closed.jaxpr)
+    return found
+
+
+def _pairs_setup(c=3, t=20, m=2):
+    cfg = _cfg()
+    w = (moe_lib.init_moe(KEY, cfg, dtype=jnp.float32)["experts"], None)
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(5), 3)
+    x = jax.random.normal(k1, (c, t, cfg.d_model))
+    ids = jnp.argsort(jax.random.normal(k2, (c, t, 8)), -1)[..., :2]
+    cts = jax.random.normal(k3, (c, m, t, 2, cfg.d_model))
+    return w, x, ids.astype(jnp.int32), cts
+
+
+def _objective_grads(w):
+    """Per client: the vjp of its pairs, pulled for M cotangents under
+    an inner vmap (``ppo.per_objective_grads``' batched pulls)."""
+    def one(x, ids, cts):
+        _, vjp = jax.vjp(lambda x_: moe_lib.expert_pairs(x_, ids, w), x)
+        return jax.vmap(lambda ct: vjp(ct)[0])(cts)
+    return one
+
+
+@pytest.mark.parametrize("batching", ["clients", "clients_objectives"])
+def test_a_batched_call_equals_one_call_on_the_folded_rows(batching,
+                                                           monkeypatch):
+    """Under ``vmap`` over clients, and under nested ``vmap`` (clients,
+    then the M objectives' cotangents), the grouped layout computes what
+    one unbatched call computes on every client's (and objective's)
+    rows folded into one row axis."""
+    _force(monkeypatch, "grouped")
+    w, x, ids, cts = _pairs_setup()
+    c, t, d = x.shape
+    m = cts.shape[1]
+    if batching == "clients":
+        got = jax.jit(jax.vmap(moe_lib.expert_pairs, (0, 0, None)))(
+            x, ids, w)
+        want = jax.jit(moe_lib.expert_pairs)(
+            x.reshape(c * t, d), ids.reshape(c * t, 2), w)
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(want).reshape(got.shape),
+                                   rtol=1e-6, atol=1e-6)
+        return
+    got = jax.jit(jax.vmap(_objective_grads(w)))(x, ids, cts)
+    rows = lambda a: jnp.broadcast_to(  # noqa: E731
+        a[:, None], (c, m) + a.shape[1:]).reshape((c * m * t,) + a.shape[2:])
+    _, vjp = jax.vjp(lambda x_: moe_lib.expert_pairs(x_, rows(ids), w),
+                     rows(x))
+    want = vjp(cts.reshape((c * m * t,) + cts.shape[3:]))[0]
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(want).reshape(got.shape),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_the_fold_reaches_the_kernels_and_the_backward_forms_no_weight_grad(
+        monkeypatch):
+    """Lowered under ``vmap`` over C clients (and M objectives inside):
+    each grouped matmul takes all C*T*k pairs (C*M*T*k in the backward
+    pass) as the rows of one 2-D operand, with no client axis, and the
+    VJP of the forward pass holds the three forward and three input-
+    gradient matmuls only: no grouped weight gradient (an (E, ., .)
+    output) is formed."""
+    _force(monkeypatch, "grouped")
+    w, x, ids, cts = _pairs_setup()
+    c, t, _ = x.shape
+    m, e, tile = cts.shape[1], 8, 8
+    padded = lambda n: -(-n // tile) * tile + e * tile  # noqa: E731
+
+    fwd = _pallas_calls(jax.make_jaxpr(
+        jax.vmap(moe_lib.expert_pairs, (0, 0, None)))(x, ids, w))
+    assert [ins[-2][0] for ins, _ in fwd] == [padded(c * t * 2)] * 3
+    grads = _pallas_calls(jax.make_jaxpr(jax.vmap(_objective_grads(w)))(
+        x, ids, cts))
+    assert len(grads) == 6
+    assert [ins[-2][0] for ins, _ in grads] == (
+        [padded(c * t * 2)] * 3 + [padded(c * m * t * 2)] * 3)
+    for ins, outs in fwd + grads:
+        assert len(ins[-2]) == 2                   # rows x features
+        assert [len(o) for o in outs] == [2]       # no weight gradient
+
+
+@pytest.mark.parametrize("form,want", [("einsum", 4.0), ("grouped", None)])
+def test_a_round_reports_the_rows_per_routed_pair(form, want, monkeypatch):
+    """``round/moe_rows_per_pair``: E/k = 4 where the einsum layout runs
+    the policy forward; in the grouped layout each expert's rows rounded
+    up to the row tile, at most 1 + E * (tile - 1) / pairs."""
+    from repro.fed.engine import EngineConfig, FederatedTrainer
+    _force(monkeypatch, form)
+    cfg = dataclasses.replace(_cfg(), name=f"mixtral-rows-{form}")
+    fc = FIRMConfig(n_objectives=2, n_clients=2, local_steps=1,
+                    batch_size=2)
+    ec = EngineConfig(algorithm="firm", max_new=6, prompt_len=4, seed=0,
+                      uplink_codec="int8+ef")
+    tr = FederatedTrainer(cfg, fc, ec)
+    summary = tr.run_round()
+    got = summary["moe_rows_per_pair"]
+    assert tr.obs.values("round/moe_rows_per_pair") == [got]
+    if want is not None:
+        assert got == want
+        return
+    pairs = 2 * 2 * (4 + 6) * 2                    # C * B * S * k
+    assert 1.0 <= got <= 1 + 8 * (8 - 1) / pairs

@@ -1,6 +1,7 @@
 """The round's layers on the device trace's clock: named programs, host
 spans in a profiler trace, and the map from compiled ops to layers
 (``repro.obs.jitwatch``)."""
+import dataclasses
 import glob
 import re
 
@@ -245,3 +246,42 @@ def test_an_inactive_wrapper_keeps_only_the_first_signature(monkeypatch):
     assert log.compile_count == 1
     assert jitwatch._PROGRAMS["test_inactive"].sig[0][0].shape == (7,)
     assert jitwatch.span("x") is jitwatch.span("y")   # no profiler: no-op
+
+
+def test_the_grouped_expert_layer_maps_to_its_layers_in_every_pass(
+        monkeypatch):
+    """With the expert layer in its grouped layout, every instruction of
+    its grouped matmuls (the Pallas kernels, lowered interpreted here; on
+    the chip ``test_tpu_compile`` finds the Mosaic calls there) is layer
+    ``moe/experts`` and the layout's rows and gather ``moe/route``, each
+    with the pass that runs the model as its phase."""
+    from repro.models import moe
+    monkeypatch.setattr(moe, "GROUPED_MIN_ROWS", 1)
+    monkeypatch.setattr(moe, "_row_tile", lambda n, e: 8)
+    cfg = dataclasses.replace(
+        get_config("mixtral-8x7b").reduced(n_layers=2, d_model=64,
+                                           vocab=256),
+        name="mixtral-grouped-layer-map")
+    fc = FIRMConfig(n_objectives=2, n_clients=2, local_steps=1,
+                    batch_size=2, beta=0.05)
+    ec = EngineConfig(algorithm="firm", max_new=6, prompt_len=4, seed=0,
+                      uplink_codec="int8+ef")
+    tr = FederatedTrainer(cfg, fc, ec)
+    with jitwatch.record():
+        tr.run(1)
+    pm, = jitwatch.layer_map({"vec_round[firm]"}).values()
+    prog = jitwatch._PROGRAMS["vec_round[firm]"]
+    args, kwargs = prog.sig
+    text = prog.jitted.lower(*args, **kwargs).compile().as_text()
+    kernel_ops = {m.group(1) for m in re.finditer(
+        r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*op_name="[^"]*/jit\(gmm\)/',
+        text, re.M)} & set(pm.ops)
+    assert kernel_ops
+    assert {pm.ops[op] for op in kernel_ops} == {"moe/experts"}
+    placed = {(pm.ops[op], pm.phases[op]) for op in pm.ops}
+    for phase in ("generate/prefill", "generate/decode", "ref_forward",
+                  "local_step/grads"):
+        assert ("moe/experts", phase) in placed, phase
+        assert ("moe/route", phase) in placed, phase
+    assert {pm.phases[op] for op in kernel_ops} >= {
+        "generate/prefill", "ref_forward", "local_step/grads"}
