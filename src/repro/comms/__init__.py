@@ -12,10 +12,11 @@ Pallas-kernel hot paths (see README.md in this package).
 Analytic per-round models live in repro.core.comms; this package is the
 measured counterpart wired through repro.fed.engine.
 
-Every codec also implements the traced contract used by the fused
-multi-round engine (``roundtrip_traced*`` with explicit array state,
-``nbytes_static`` exact byte accounting, ``Payload.nbytes_entropy``
-ideal-coder estimates) — see README.md and repro.fed.engine.
+A codec implements one flat-vector transform; the base class derives
+from it the traced round trip (``encode_decode_traced*`` /
+``roundtrip_traced*`` with explicit array state) and, as one jitted
+program of that round trip, the host Payload boundary above — see
+codec.py and README.md.
 """
 from repro.comms.codec import (Codec, DeltaCodec, ErrorFeedback,
                                IdentityCodec, Payload, flat_to_tree,

@@ -28,10 +28,6 @@ def _matrix_shape(d: int):
 
 
 class LowRankCodec(Codec):
-    # the jitted QR and matmuls do not reproduce the eager ones bit for
-    # bit, so the engine keeps this codec's downlink on the host path
-    traced_matches_host = False
-
     def __init__(self, rank: int = 4, power_iters: int = 1):
         if rank < 1:
             raise ValueError(f"lowrank rank must be >= 1, got {rank}")

@@ -15,7 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.comms.codec import Codec, Payload
+from repro.comms.codec import Codec
 from repro.kernels import ops
 from repro.kernels.quantize import BLOCK, _DET_BITS
 
@@ -89,74 +89,14 @@ class QuantizeCodec(Codec):
     def meta_static(self, d: int):
         return {"bits": self.bits}
 
-    # -- stacked-client batched path ------------------------------------
-    def _quantize_stacked(self, flats, keys):
-        """(C, d) -> one kernel dispatch over the concatenated blocks.
-
-        Each client's blocks are quantized row-independently, so
-        concatenating the per-client (rows, BLOCK) groups along the row
-        axis and running ONE quantize kernel yields codes/scales
-        bit-identical to C per-client calls (the per-client random bits
-        still come from that client's key)."""
-        c, d = flats.shape
-        rows = -(-d // BLOCK)
-        pad = rows * BLOCK - d
-        x = jnp.pad(flats, ((0, 0), (0, pad))) if pad else flats
-        x = x.reshape(c * rows, BLOCK)
-        det = jnp.full((rows, BLOCK), _DET_BITS, jnp.uint32)
-        if self.stochastic and keys is not None:
-            # per-row None keys fall back to round-to-nearest for that
-            # client only, matching C per-client encode calls
-            rbits = jnp.concatenate(
-                [det if k is None else
-                 jax.random.bits(k, (rows, BLOCK), jnp.uint32)
-                 for k in keys])
-        else:
-            rbits = jnp.tile(det, (c, 1))
-        codes, scales = ops.quantize(x, rbits, self.qmax,
-                                     use_pallas=self.use_pallas)
-        return codes, scales, rows
-
-    def _stacked_payloads(self, codes, scales, rows, c, spec, d):
-        payloads = []
-        for i in range(c):
-            ci = codes[i * rows:(i + 1) * rows]
-            if self.bits == 4:
-                ci = pack_int4(ci)
-            payloads.append(Payload(
-                self.name,
-                {"codes": ci, "scales": scales[i * rows:(i + 1) * rows]},
-                {"bits": self.bits, "spec": spec, "d": d}))
-        return payloads
-
-    def encode_stacked(self, flats, spec, states=None, *, keys=None):
-        c, d = flats.shape
-        codes, scales, rows = self._quantize_stacked(flats, keys)
-        payloads = self._stacked_payloads(codes, scales, rows, c, spec, d)
-        return payloads, list(states) if states is not None else [None] * c
-
-    def roundtrip_stacked(self, flats, spec, states=None, *, keys=None):
-        c, d = flats.shape
-        codes, scales, rows = self._quantize_stacked(flats, keys)
-        payloads = self._stacked_payloads(codes, scales, rows, c, spec, d)
-        decoded = ops.dequantize(codes, scales, use_pallas=self.use_pallas)
-        decoded = decoded.reshape(c, rows * BLOCK)[:, :d]
-        return (payloads,
-                list(states) if states is not None else [None] * c,
-                decoded)
-
-    # -- traced in-graph path -------------------------------------------
-    def encode_decode_traced_stacked(self, flats, *, keys=None):
-        """Same batched quantize/dequantize as ``roundtrip_stacked`` with
-        codes/scales staged in-graph — ONE kernel dispatch over all C
-        clients' blocks, bit-identical rows to per-client
-        ``roundtrip_traced`` calls — and the wire buffers (int4 packed)
-        returned alongside the decode, in the concatenated-row layout
-        ``split_stacked_arrays`` slices.  ``keys`` is a (C, 2)
-        key array (stacked callers always supply per-client keys).  The
-        wire boundary is marked with (best-effort) optimization barriers
-        — see ``Codec.roundtrip_traced`` for what they do and do not
-        guarantee."""
+    # -- stacked traced pair: one kernel over all clients' blocks --------
+    def encode_decode_traced_stacked(self, flats, states=(), *, keys=None):
+        """The C clients' padded (rows, BLOCK) groups concatenate along
+        the row axis into ONE quantize and ONE dequantize kernel.  Blocks
+        are quantized row-independently and each client's rounding bits
+        come from its own key, so row c is bit-identical to a per-client
+        ``encode_decode_traced``.  The wire buffers (int4 packed) stay in
+        the concatenated-row layout ``split_stacked_arrays`` slices."""
         flats = jax.lax.optimization_barrier(flats)
         c, d = flats.shape
         rows = -(-d // BLOCK)
@@ -176,13 +116,7 @@ class QuantizeCodec(Codec):
         decoded = jax.lax.optimization_barrier(
             decoded.reshape(c, rows * BLOCK)[:, :d])
         wire = pack_int4(codes) if self.bits == 4 else codes
-        return {"codes": wire, "scales": scales}, decoded
-
-    def roundtrip_traced_stacked(self, flats, states=(), *, keys=None):
-        """Decode-only view of ``encode_decode_traced_stacked`` (the
-        unused wire buffers are dead code the compiler drops)."""
-        _, decoded = self.encode_decode_traced_stacked(flats, keys=keys)
-        return decoded, states
+        return {"codes": wire, "scales": scales}, decoded, states
 
     def split_stacked_arrays(self, arrays, c, d):
         """Slice the concatenated-row codes/scales into per-client wire

@@ -65,10 +65,10 @@ class EngineConfig:
     # stacked client axis (falls back per the capability rules in plan())
     vectorized_clients: bool = True
     # fuse R federated rounds into ONE jitted program (round-level
-    # lax.scan with the traced codec contract): 1 = per-round dispatch;
-    # >1 amortizes Python dispatch and the per-round host transfer over
-    # R rounds.  Granted only for fusable algorithms on the
-    # single-cohort vectorized path with traceable codecs.
+    # lax.scan with the codecs' traced round trips): 1 = per-round
+    # dispatch; >1 amortizes Python dispatch and the per-round host
+    # transfer over R rounds.  Granted only for fusable algorithms on
+    # the single-cohort vectorized path.
     fused_rounds: int = 1
     # extra telemetry sinks for the round-summary pipeline
     # (repro.obs.metrics specs, e.g. "jsonl:metrics.jsonl" or
@@ -120,8 +120,7 @@ def resolve_local_mode(algorithm: Algorithm,
     return "cohort", plan, f"{len(plan)} static-config cohorts"
 
 
-def resolve_fused(algorithm: Algorithm, local_mode: str, uplink_codec,
-                  downlink_codec):
+def resolve_fused(algorithm: Algorithm, local_mode: str):
     """May whole rounds ride the round-level ``lax.scan``?  Returns
     ``(ok, reason)``; like ``resolve_local_mode`` this is shared by the
     engine's ``_fused_mode`` probe and the planner."""
@@ -131,9 +130,6 @@ def resolve_fused(algorithm: Algorithm, local_mode: str, uplink_codec,
     if local_mode != "vec":
         return False, ("fused rounds need the single-cohort vectorized "
                        f"path (local mode is {local_mode!r})")
-    if not (getattr(uplink_codec, "traceable", False)
-            and getattr(downlink_codec, "traceable", False)):
-        return False, "codec does not support the traced contract"
     return True, ("single-cohort vectorized round body stages into the "
                   "round-level scan")
 
@@ -264,7 +260,7 @@ def plan(spec: RunSpec, d_trainable: Optional[int] = None
 
     ul = make_codec(ec.uplink_codec)
     dl = make_codec(ec.downlink_codec)
-    fused_ok, fused_reason = resolve_fused(alg, mode, ul, dl)
+    fused_ok, fused_reason = resolve_fused(alg, mode)
     chunk = max(1, int(ec.fused_rounds))
     if chunk <= 1:
         fused_ok = False

@@ -48,12 +48,13 @@ equivalent to the per-client loop.  Algorithms declaring
 back to the loop when configs diverge; algorithms whose server exchange
 is host-driven (``traced_server_exchange=False``) route the vectorized
 phase through their own ``exchange_phase_vectorized`` hook.  The uplink
-codec runs at a *stacked* Payload boundary
-(``Codec.roundtrip_stacked``): quantize codecs encode all C client
-deltas in one batched kernel dispatch, byte-identical to per-client
-encodes; for ``+ef`` codecs that boundary is one program that stacks
-the per-client residuals and keys and returns each client's wire
-buffers and new residual, so the host neither stacks nor slices.
+codec runs at its *stacked* Payload boundary
+(``Codec.roundtrip_stacked``): one program of the codec's traced round
+trip that stacks the per-client states and keys, encodes all C client
+deltas (quantize codecs in one batched kernel, byte-identical to
+per-client encodes) and returns each client's wire buffers and new
+state, so the host neither stacks nor slices; the Payloads are built
+from those buffers.
 
 On the per-round vectorized path every array operation of a round runs
 inside a named program (``repro.obs.jitwatch``), with no eager op
@@ -80,21 +81,22 @@ extraction, the stacked uplink roundtrip, and the FedAvg aggregate —
 into a round-level ``jax.lax.scan``: R rounds run as ONE jitted dispatch
 with ONE host transfer at the end of the chunk (see ``FusedCarry`` for
 the donated carry layout and ``_jit_fused_rounds`` for the body).  The
-codecs run through their traced contract (``repro.comms``:
-``roundtrip_traced*`` with explicit array state, ``nbytes_static`` byte
-accounting), so the comms ledger and the scheduler's time models keep
-exact bytes with zero per-round host syncs.  Results are bit-identical
-to the per-round path: the body replicates ``run_round``'s PRNG split
-sequence exactly, and the error-feedback residual is computed in the
-same jitted composition on both paths (XLA contracts the dequantize
-multiply into the residual subtract; doing it identically everywhere is
-what keeps the trajectories exact).  ``run()`` chunks the horizon by R
-when ``api.resolve_fused`` grants it — the algorithm declares
-``fusable`` (which requires a traced server exchange), the population
-forms one cohort, and both codecs support the traced contract — and
-falls back to per-round execution otherwise; the ``sync`` scheduler
-policy rides the fused path unchanged while the deadline/fedbuff
-policies are host-driven between dispatches and stay per-round.
+codecs run the same traced round trips the per-round path's programs
+run (``repro.comms``: ``roundtrip_traced*`` with explicit array state,
+``nbytes_static`` byte accounting), so the comms ledger and the
+scheduler's time models keep exact bytes with zero per-round host
+syncs.  Results are bit-identical to the per-round path: the body
+replicates ``run_round``'s PRNG split sequence exactly, and the
+error-feedback residual is computed in the same jitted composition on
+both paths (XLA contracts the dequantize multiply into the residual
+subtract; doing it identically everywhere is what keeps the
+trajectories exact).  ``run()`` chunks the horizon by R when
+``api.resolve_fused`` grants it — the algorithm declares ``fusable``
+(which requires a traced server exchange) and the population forms one
+cohort — and falls back to per-round execution otherwise; the ``sync``
+scheduler policy rides the fused path unchanged while the
+deadline/fedbuff policies are host-driven between dispatches and stay
+per-round.
 """
 from __future__ import annotations
 
@@ -519,8 +521,9 @@ def _jit_fused_rounds(cfg: ModelConfig, cfc: FIRMConfig, kernel: str,
             for _p in range(n_part):
                 rng, kk = _split_next(rng)
                 up_keys.append(kk)
-            decoded, ul_part = ul.roundtrip_traced_stacked(
-                flat_deltas, ul_part, keys=jnp.stack(up_keys))
+            with jax.named_scope("uplink_codec"):
+                decoded, ul_part = ul.roundtrip_traced_stacked(
+                    flat_deltas, ul_part, keys=jnp.stack(up_keys))
 
             with jax.named_scope("aggregate"):
                 w = fedavg.staleness_weights(
@@ -733,9 +736,7 @@ class FederatedTrainer:
         """(eligible, cohort cfc) for the fused multi-round program —
         ``api.resolve_fused`` over the full population's local mode."""
         mode, plan = self._local_phase_mode(list(range(self.fc.n_clients)))
-        ok, _ = api_lib.resolve_fused(self.algorithm, mode,
-                                      self.uplink_codec,
-                                      self.downlink_codec)
+        ok, _ = api_lib.resolve_fused(self.algorithm, mode)
         if not ok:
             return False, None
         return True, plan[0].cfc
@@ -855,21 +856,13 @@ class FederatedTrainer:
         return summary
 
     def _downlink(self):
-        """θ_t through the downlink codec: (decoded broadcast, wire bytes
-        per receiver).  A codec whose traced round trip matches its host
-        round trip runs as one program that also draws the stream's next
-        key; any other codec draws the key and round-trips on the host."""
-        codec = self.downlink_codec
-        if codec.traced_matches_host:
-            self._rng, broadcast, self._downlink_state = _jit_downlink(
-                self.ec.downlink_codec)(self._rng, self.global_trainable,
-                                        self._downlink_state)
-            return broadcast, codec.nbytes_static(self.d_trainable)
-        with jitwatch.span("round/keys"):
-            key = self._next_key()
-        payload, self._downlink_state, broadcast = codec.roundtrip(
-            self.global_trainable, self._downlink_state, key=key)
-        return broadcast, comms.measured_bytes(payload)
+        """θ_t through the downlink codec as one program that also draws
+        the stream's next key: (decoded broadcast, wire bytes per
+        receiver)."""
+        self._rng, broadcast, self._downlink_state = _jit_downlink(
+            self.ec.downlink_codec)(self._rng, self.global_trainable,
+                                    self._downlink_state)
+        return broadcast, self.downlink_codec.nbytes_static(self.d_trainable)
 
     # ------------------------------------------------- fused rounds path
     def run_rounds_fused(self, rounds: int) -> List[dict]:
@@ -886,9 +879,8 @@ class FederatedTrainer:
         if not ok:
             raise ValueError(
                 "fused_rounds requires a fusable algorithm (traced server "
-                "exchange, vmap-safe local step), one full-population "
-                "static-config cohort, and codecs supporting the traced "
-                "contract; use run()/run_round() instead")
+                "exchange, vmap-safe local step) and one full-population "
+                "static-config cohort; use run()/run_round() instead")
         fc = self.fc
         c_all = fc.n_clients
         n_part = min(c_all, max(1, int(round(fc.participation * c_all))))

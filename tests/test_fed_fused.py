@@ -260,28 +260,32 @@ def test_roundtrip_traced_matches_host(spec):
                                           np.asarray(dec_t))
 
 
-def test_traced_stacked_matches_host_stacked():
+@pytest.mark.parametrize("spec", ["identity", "int8", "int4", "topk:0.05",
+                                  "lowrank:4", "int8+ef", "topk:0.05+ef",
+                                  "delta+int8"])
+def test_traced_stacked_matches_host_stacked(spec):
     """roundtrip_traced_stacked (the fused uplink boundary) matches the
-    host stacked path bit-for-bit, including EF residual states."""
+    host stacked Payload boundary bit for bit over two rounds: decodes
+    and the codec states threaded between them."""
     key = jax.random.PRNGKey(2)
     c, d = 3, 5000
-    flats = jax.random.normal(key, (c, d)) * 0.01
-    tspec = tree_to_flat({"w": flats[0]})[1]
-    keys = jnp.stack([jax.random.fold_in(key, i) for i in range(c)])
-    for spec in ("identity", "int8", "int8+ef"):
-        ch, ct = make_codec(spec), make_codec(spec)
-        _, ns, dec_h = ch.roundtrip_stacked(flats, tspec, [None] * c,
-                                            keys=list(keys))
-        ts = ct.init_states_traced(d, [None] * c)
-        dec_t, ts2 = jax.jit(
-            lambda f, s, k, _ct=ct: _ct.roundtrip_traced_stacked(
-                f, s, keys=k))(flats, ts, keys)
+    tspec = tree_to_flat({"w": jnp.zeros((d,))})[1]
+    ch, ct = make_codec(spec), make_codec(spec)
+    host, traced = [None] * c, ct.init_states_traced(d, [None] * c)
+    fn = jax.jit(lambda f, s, k: ct.roundtrip_traced_stacked(f, s, keys=k))
+    for t in range(2):
+        flats = jax.random.normal(jax.random.fold_in(key, t), (c, d)) * 0.01
+        keys = jnp.stack([jax.random.fold_in(key, 10 * t + i)
+                          for i in range(c)])
+        _, host, dec_h = ch.roundtrip_stacked(flats, tspec, host,
+                                              keys=list(keys))
+        dec_t, traced = fn(flats, traced, keys)
         np.testing.assert_array_equal(np.asarray(dec_h), np.asarray(dec_t))
-        if spec.endswith("+ef"):
-            host_rows = ct.states_to_host(ts2, c)
-            for i in range(c):
-                np.testing.assert_array_equal(np.asarray(ns[i]),
-                                              np.asarray(host_rows[i]))
+        for h, r in zip(jax.tree_util.tree_leaves(host),
+                        jax.tree_util.tree_leaves(ct.states_to_host(traced,
+                                                                    c)),
+                        strict=True):
+            np.testing.assert_array_equal(np.asarray(h), np.asarray(r))
 
 
 def test_payload_entropy_estimate():
